@@ -17,6 +17,8 @@ def main() -> None:
                          "drive the serving path (farm)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (farm, fig3_design_space, fig4_cost_curves,
                             fig5_pareto, table1_opcounts, table2_training,
                             table3_dse, throughput)

@@ -174,4 +174,6 @@ async def production_tier():
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     asyncio.run(main())

@@ -30,9 +30,8 @@ def params():
 
 def generate(x0, n_steps, *, interpret=None):
     """Run the frozen oscillator core. x0: (S, I) normalized seeds."""
-    import jax
     if interpret is None:
-        interpret = not any(d.platform == "tpu" for d in jax.devices())
+        interpret = ops.resolve_backend() == "pallas_interpret"
     p = params()
     return chaotic_ann_pallas(
         p["w1"], p["b1"], p["w2"], p["b2"], jnp.asarray(x0, DTYPE),

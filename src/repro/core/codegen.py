@@ -71,9 +71,8 @@ def generate(x0, n_steps, *, interpret=None):
     carrying ``lattice_meta``/``coupling``) dispatches its block-coupled
     kernel path with no change to the generated surface.
     """
-    import jax
     if interpret is None:
-        interpret = not any(d.platform == "tpu" for d in jax.devices())
+        interpret = ops.resolve_backend() == "pallas_interpret"
     backend = "pallas_interpret" if interpret else "pallas"
     return ops.chaotic_trajectory(
         params(), jnp.asarray(x0, DTYPE), n_steps, activation=ACTIVATION,

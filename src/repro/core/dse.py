@@ -195,24 +195,30 @@ def stacked_gang_vmem_bytes(c: Candidate, n_cores: int) -> int:
     """VMEM working set of one ``chaotic_ann_gang_stacked_pallas`` launch
     stacking ``n_cores`` equal pools on the sublane axis.
 
-    Everything the solo instance keeps per core — state carry, live
-    hidden, x0 block — is resident for all C cores at once, the
-    pre-broadcast weight tables are (i_dim, C*h_pad)/(h_dim, C*i_pad),
-    and the words block is (t_block/2, C, s_block) double-buffered.
-    This is the planner's stacked-layout feasibility check: the pool
-    size where this crosses ``VMEM_USABLE`` is the stacked-layout VMEM
-    cliff, past which the planner must fall back to a lane-concat
-    (ragged/padded) launch.
+    The kernel keeps the group dimension-major, the C cores on sublanes:
+    state and x0 blocks are (I, C, s_block), the live hidden is
+    (H, C, s_block), and every weight is a (C, 1) column, which Mosaic
+    pads to a whole (sublane x 128-lane) tile.  Pallas double-buffers
+    every block, the words block (t_block/2, C, s_block) included, and
+    the VPU computes a half-width group at f32 width.  This is the
+    planner's stacked-layout feasibility check: the pool size where this
+    crosses ``VMEM_USABLE`` is the stacked-layout VMEM cliff, past which
+    the planner must fall back to a lane-concat (ragged/padded) launch.
+    It is checked against the TPU compiler's own figure
+    (tests/test_tpu_compile.py): the compiler needs 97.66 MiB for 512
+    stacked bf16 3-8-3 cores at s_block 128, t_block 256, unroll 8.
     """
     C = max(1, int(n_cores))
     d = c.dtype_bytes
-    tables = (c.i_dim * C * c.h_pad + C * c.h_pad
-              + c.h_dim * C * c.i_pad + C * c.i_pad) * d
-    state = C * c.i_pad * c.s_block * d
-    hidden = C * c.h_pad * c.s_block * d * c.unroll
-    x0_blk = C * c.i_pad * c.s_block * d
-    out_blk = 2 * (c.t_block // 2) * C * c.s_block * 4   # uint32 words
-    return tables + state + hidden + x0_blk + out_blk
+    cp = _pad(C, SUBLANES * (4 // d))        # a sublane tile of this dtype
+    c32 = _pad(C, SUBLANES)                  # ... and of f32 / uint32
+    s = c.s_block
+    tables = 2 * (2 * c.i_dim * c.h_dim + c.h_dim + c.i_dim) * cp * LANES * d
+    state = 2 * 2 * c.i_dim * cp * s * d     # x0 in, final state out
+    hidden = c.h_dim * c32 * s * 4 * c.unroll
+    temps = 2 * (c.i_dim + c.h_dim) * c32 * s * 4   # one row's two steps
+    out_blk = 2 * (c.t_block // 2) * c32 * s * 4    # uint32 words
+    return tables + state + hidden + temps + out_blk
 
 
 # ---------------------------------------------------------------------------

@@ -285,13 +285,11 @@ def shard_stream_pool(fn, mesh: Mesh, axis: str = "data"):
     the words gather back on the lane axis.  The mesh axis size must
     divide S.
     """
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axis, None), P(axis)),
         out_specs=(P(None, axis), P(axis, None)),
-        check_rep=False)
+        check_vma=False)
 
 
 def strip_dp_axes(pspec_tree: PyTree, spec: MeshSpec) -> PyTree:

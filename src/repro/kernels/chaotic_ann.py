@@ -30,15 +30,17 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # TPU-specific memory spaces (present in jax 0.8.x)
-    from jax.experimental.pallas import tpu as pltpu
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    _VMEM = None
+from repro.core.dse import VMEM_USABLE
 
 LANES = 128
 SUBLANES = 8
+
+# Every kernel's scoped-VMEM limit: the one budget the DSE's ``fits_vmem``,
+# ``stacked_gang_vmem_bytes`` and the gang planner check against.  Without
+# it Mosaic applies its default 16 MiB scoped limit.
+_COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_USABLE)
 
 
 def _activation(name: str):
@@ -62,20 +64,22 @@ def _lattice_delta(x, lattice):
     """Diffusive-coupling increment of a block-coupled lattice, as wrapped
     sublane rolls — the VPU form of the block-sparse coupling operator.
 
-    x: (R, s) with R a whole number of ``period = n_nodes * base_dim``
-    row groups (one for the solo kernel, C for the sublane-stacked gang —
-    the node index is periodic per group, so ONE formula serves both
-    layouts).  Each component row r accumulates its graph neighbours:
+    x: (R, ...) with the lattice component on the leading axis and R a
+    whole number of ``period = n_nodes * base_dim`` row groups — (I, s)
+    for the solo kernel, (I, C, s) for the stacked gang; the node index is
+    periodic per group, so ONE formula serves every layout.  Each
+    component row r accumulates its graph neighbours:
     ``delta[r] = strength * (sum_neighbours x[r'] - deg * x[r])``, where
     neighbour rows are reached by rolling the whole block by +-stride and
     correcting the ring-wrap rows with an iota mask (1-D iota is illegal
-    on TPU; ``broadcasted_iota`` over (R, 1)).  Exactly the same jnp
+    on TPU; ``broadcasted_iota`` over (R, 1, ...)).  Exactly the same jnp
     expression runs in every kernel AND the ``ref`` backend scan, so the
     coupled step is bitwise identical across all of them.
     """
     n_nodes, base_dim, topology, strength = lattice
     period = n_nodes * base_dim
-    r = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], 1), 0)
+    r = jax.lax.broadcasted_iota(
+        jnp.int32, (x.shape[0],) + (1,) * (x.ndim - 1), 0)
     node = (r % period) // base_dim
 
     def ring_pair(idx, n_ring, stride):
@@ -120,13 +124,39 @@ def _round_half(v, dtype):
     multi-step kernel body can carry MORE precision between steps than a
     one-step-per-carry scan, silently breaking bitwise kernel/ref identity
     (the carry of a scan is materialized at bf16; a fused body's isn't).
-    ``reduce_precision`` cannot be elided, so the state rounds exactly once
-    per step everywhere.  f32 states pass through untouched.
+    Rounding on the integer bits (``round_bf16_bits``) cannot be elided,
+    so the state rounds exactly once per step everywhere.  f32 states pass
+    through untouched.
     """
     if jnp.dtype(dtype) == jnp.bfloat16:
-        fi = jnp.finfo(jnp.bfloat16)
-        v = jax.lax.reduce_precision(v, fi.nexp, fi.nmant)
+        v = round_bf16_bits(v.astype(jnp.float32))
     return v.astype(dtype)
+
+
+def round_bf16_bits(v):
+    """f32 -> f32 rounded to bf16 precision, on the integer bits.
+
+    Round to nearest, ties to even: add ``0x7FFF`` plus the lowest kept
+    mantissa bit, then clear the 16 dropped bits.  Bit for bit equal to
+    ``lax.reduce_precision(v, 8, 7)`` — ties, +-inf, overflow to inf and
+    subnormals included; NaNs pass through unchanged — but built from
+    integer ops, which Mosaic lowers (it has no ``reduce_precision``) and
+    no float simplification can remove.
+    """
+    u = jax.lax.bitcast_convert_type(v, jnp.uint32)
+    lsb = (u >> jnp.uint32(16)) & jnp.uint32(1)
+    r = (u + jnp.uint32(0x7FFF) + lsb) & jnp.uint32(0xFFFF0000)
+    return jnp.where(jnp.isnan(v), v,
+                     jax.lax.bitcast_convert_type(r, jnp.float32))
+
+
+def _dot(a, b):
+    """MXU contraction into an f32 accumulator.  f32 operands contract at
+    full f32 precision: a TPU's default f32 dot (Mosaic's and XLA's alike)
+    takes one bf16 pass, which would serve an f32 core's words from bf16
+    products on the chip and match neither interpret mode nor the oracle."""
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jnp.dot(a, b, precision=prec, preferred_element_type=jnp.float32)
 
 
 def _make_step(w1, b1, w2, b2, *, activation: str, compute_unit: str,
@@ -147,28 +177,25 @@ def _make_step(w1, b1, w2, b2, *, activation: str, compute_unit: str,
 
     def couple(x):
         if cpl is not None:
-            return _round_half(
-                jnp.dot(cpl, x, preferred_element_type=jnp.float32), x.dtype)
+            return _round_half(_dot(cpl, x), x.dtype)
         return _lattice_delta(x, lattice)
 
     def one_step(x):
         if compute_unit == "mxu":
-            h = phi(_round_half(
-                jnp.dot(w1.T, x, preferred_element_type=jnp.float32),
-                x.dtype) + b1)
-            y = _round_half(
-                jnp.dot(w2.T, h, preferred_element_type=jnp.float32), x.dtype)
+            h = phi(_round_half(_dot(w1.T, x), x.dtype) + b1)
+            y = _round_half(_dot(w2.T, h), x.dtype)
             y = y + b2
         else:
             # VPU path: broadcast-FMA over lanes; static unroll over tiny
-            # dims.
+            # dims.  w1[i] / w2[j] are weight columns laid out outside the
+            # kernel (``_pad_params``).
             h = jnp.zeros((w1.shape[1], x.shape[1]), x.dtype)
             for i in range(i_dim):
-                h = h + w1[i, :][:, None] * x[i, :][None, :]
+                h = h + w1[i] * x[i:i + 1, :]
             h = phi(h + b1)
             y = jnp.zeros_like(x)
             for j in range(h_dim):
-                y = y + w2[j, :][:, None] * h[j, :][None, :]
+                y = y + w2[j] * h[j:j + 1, :]
             y = y + b2
         if lattice is not None:
             y = y + couple(x)
@@ -202,12 +229,44 @@ def _prep_lattice(lattice, coupling, compute_unit: str, i_dim: int,
     return True, jnp.asarray(coupling, dtype)
 
 
+def _pad_params(w1, b1, w2, b2, *, i_pad: int, h_pad: int, dtype,
+                compute_unit: str):
+    """Zero-padded kernel weight operands, with or without a leading core
+    axis: w1 (.., I, H), b1 (.., H), w2 (.., H, I), b2 (.., I) become
+    w1 (.., I_pad, H_pad), b1 (.., H_pad, 1), w2 (.., H_pad, I_pad),
+    b2 (.., I_pad, 1).  The vpu step reads weights as (rows, 1) columns,
+    so for vpu w1/w2 get a trailing unit axis: ``w1[i]`` is the column
+    that multiplies state row ``i``.  Mosaic cannot turn a weight row
+    into a column inside the kernel, so the layout is made here.
+    """
+    lead = w1.shape[:-2]
+
+    def pad(a, shape):
+        z = jnp.zeros(lead + shape, dtype)
+        live = tuple(slice(0, n) for n in a.shape[len(lead):])
+        return z.at[(Ellipsis,) + live].set(a.astype(dtype))
+
+    w1p = pad(w1, (i_pad, h_pad))
+    b1p = pad(b1[..., None], (h_pad, 1))
+    w2p = pad(w2, (h_pad, i_pad))
+    b2p = pad(b2[..., None], (i_pad, 1))
+    if compute_unit != "mxu":
+        w1p, w2p = w1p[..., None], w2p[..., None]
+    return w1p, b1p, w2p, b2p
+
+
+def _whole(a):
+    """BlockSpec keeping all of ``a`` resident in every grid cell."""
+    return pl.BlockSpec(a.shape, lambda *_: (0,) * a.ndim)
+
+
 def _kernel(*refs, t_block: int, unroll: int, activation: str,
             compute_unit: str, i_dim: int, h_dim: int, lattice, has_cpl):
     """One (stream-block, time-block) grid cell.
 
     Ref shapes (per block):
       w1: (I_pad, H_pad)  b1: (H_pad, 1)  w2: (H_pad, I_pad)  b2: (I_pad, 1)
+        (vpu: w1/w2 with a trailing unit axis, see ``_pad_params``)
       [cpl: (I_pad, I_pad) — mxu lattice launches only]
       x0: (I_pad, s_block)      out: (t_block, I_pad, s_block)
       state (VMEM scratch): (I_pad, s_block)
@@ -282,26 +341,17 @@ def chaotic_ann_pallas(w1, b1, w2, b2, x0, coupling=None, *, n_steps: int,
     use_cpl, cplp = _prep_lattice(lattice, coupling, compute_unit,
                                   i_dim, i_pad, dtype)
 
-    w1p = jnp.zeros((i_pad, h_pad), dtype).at[:i_dim, :h_dim].set(w1.astype(dtype))
-    b1p = jnp.zeros((h_pad, 1), dtype).at[:h_dim, 0].set(b1.astype(dtype))
-    w2p = jnp.zeros((h_pad, i_pad), dtype).at[:h_dim, :i_dim].set(w2.astype(dtype))
-    b2p = jnp.zeros((i_pad, 1), dtype).at[:i_dim, 0].set(b2.astype(dtype))
+    inputs = list(_pad_params(w1, b1, w2, b2, i_pad=i_pad, h_pad=h_pad,
+                              dtype=dtype, compute_unit=compute_unit))
+    if use_cpl:
+        inputs.append(cplp)
+    in_specs = [_whole(a) for a in inputs]
     # (S, I) -> (I_pad, S_pad): streams on lanes.
     x0p = jnp.zeros((i_pad, s_pad), dtype).at[:i_dim, :s_total].set(x0.T.astype(dtype))
 
     grid = (s_pad // s_block, t_pad // t_block)
-    scratch = [_VMEM((i_pad, s_block), dtype)] if _VMEM is not None else []
+    scratch = [pltpu.VMEM((i_pad, s_block), dtype)]
 
-    in_specs = [
-        pl.BlockSpec((i_pad, h_pad), lambda s, t: (0, 0)),    # w1
-        pl.BlockSpec((h_pad, 1), lambda s, t: (0, 0)),        # b1
-        pl.BlockSpec((h_pad, i_pad), lambda s, t: (0, 0)),    # w2
-        pl.BlockSpec((i_pad, 1), lambda s, t: (0, 0)),        # b2
-    ]
-    inputs = [w1p, b1p, w2p, b2p]
-    if use_cpl:
-        in_specs.append(pl.BlockSpec((i_pad, i_pad), lambda s, t: (0, 0)))
-        inputs.append(cplp)
     in_specs.append(pl.BlockSpec((i_pad, s_block), lambda s, t: (0, s)))
     inputs.append(x0p)
 
@@ -315,6 +365,7 @@ def chaotic_ann_pallas(w1, b1, w2, b2, x0, coupling=None, *, n_steps: int,
         out_specs=pl.BlockSpec((t_block, i_pad, s_block), lambda s, t: (t, 0, s)),
         out_shape=jax.ShapeDtypeStruct((t_pad, i_pad, s_pad), dtype),
         scratch_shapes=scratch,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
 
@@ -479,25 +530,16 @@ def chaotic_ann_bits_pallas(w1, b1, w2, b2, x0, word_offset=0, coupling=None,
     use_cpl, cplp = _prep_lattice(lattice, coupling, compute_unit,
                                   i_dim, i_pad, dtype)
 
-    w1p = jnp.zeros((i_pad, h_pad), dtype).at[:i_dim, :h_dim].set(w1.astype(dtype))
-    b1p = jnp.zeros((h_pad, 1), dtype).at[:h_dim, 0].set(b1.astype(dtype))
-    w2p = jnp.zeros((h_pad, i_pad), dtype).at[:h_dim, :i_dim].set(w2.astype(dtype))
-    b2p = jnp.zeros((i_pad, 1), dtype).at[:i_dim, 0].set(b2.astype(dtype))
     x0p = jnp.zeros((i_pad, s_pad), dtype).at[:i_dim, :s_total].set(x0.T.astype(dtype))
     off = jnp.asarray(word_offset, jnp.uint32)
     offp = jnp.zeros((1, s_pad), jnp.uint32).at[0, :s_total].set(
         jnp.broadcast_to(off, (s_total,)))
 
-    in_specs = [
-        pl.BlockSpec((i_pad, h_pad), lambda s, t: (0, 0)),    # w1
-        pl.BlockSpec((h_pad, 1), lambda s, t: (0, 0)),        # b1
-        pl.BlockSpec((h_pad, i_pad), lambda s, t: (0, 0)),    # w2
-        pl.BlockSpec((i_pad, 1), lambda s, t: (0, 0)),        # b2
-    ]
-    inputs = [w1p, b1p, w2p, b2p]
+    inputs = list(_pad_params(w1, b1, w2, b2, i_pad=i_pad, h_pad=h_pad,
+                              dtype=dtype, compute_unit=compute_unit))
     if use_cpl:
-        in_specs.append(pl.BlockSpec((i_pad, i_pad), lambda s, t: (0, 0)))
         inputs.append(cplp)
+    in_specs = [_whole(a) for a in inputs]
     in_specs += [
         pl.BlockSpec((i_pad, s_block), lambda s, t: (0, s)),  # x0
         pl.BlockSpec((1, s_block), lambda s, t: (0, s)),      # offsets
@@ -520,6 +562,7 @@ def chaotic_ann_bits_pallas(w1, b1, w2, b2, x0, word_offset=0, coupling=None,
             jax.ShapeDtypeStruct((n_rows, s_pad), jnp.uint32),
             jax.ShapeDtypeStruct((i_pad, s_pad), dtype),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
 
@@ -692,14 +735,6 @@ def chaotic_ann_gang_bits_pallas(w1, b1, w2, b2, x0, core_map, word_offset=0,
     use_cpl, cplp = _prep_lattice(lattice, coupling, compute_unit,
                                   i_dim, i_pad, dtype)
 
-    w1p = jnp.zeros((n_cores, i_pad, h_pad), dtype
-                    ).at[:, :i_dim, :h_dim].set(w1.astype(dtype))
-    b1p = jnp.zeros((n_cores, h_pad, 1), dtype
-                    ).at[:, :h_dim, 0].set(b1.astype(dtype))
-    w2p = jnp.zeros((n_cores, h_pad, i_pad), dtype
-                    ).at[:, :h_dim, :i_dim].set(w2.astype(dtype))
-    b2p = jnp.zeros((n_cores, i_pad, 1), dtype
-                    ).at[:, :i_dim, 0].set(b2.astype(dtype))
     x0p = jnp.zeros((i_pad, s_total), dtype
                     ).at[:i_dim, :].set(x0.T.astype(dtype))
     off = jnp.asarray(word_offset, jnp.uint32)
@@ -713,19 +748,16 @@ def chaotic_ann_gang_bits_pallas(w1, b1, w2, b2, x0, core_map, word_offset=0,
         scalars.append(jnp.minimum(jnp.asarray(row_map, jnp.int32), n_rows))
     n_sc = len(scalars)
 
-    def _w(g, t, *maps):
-        return (maps[0][g], 0, 0)
+    def _slab(a):
+        """Core ``core_map[g]``'s slab of a weight stacked on axis 0."""
+        return pl.BlockSpec((1,) + a.shape[1:],
+                            lambda g, t, *m: (m[0][g],) + (0,) * (a.ndim - 1))
 
-    in_specs = [
-        pl.BlockSpec((1, i_pad, h_pad), _w),
-        pl.BlockSpec((1, h_pad, 1), _w),
-        pl.BlockSpec((1, h_pad, i_pad), _w),
-        pl.BlockSpec((1, i_pad, 1), _w),
-    ]
-    inputs = [w1p, b1p, w2p, b2p]
+    inputs = list(_pad_params(w1, b1, w2, b2, i_pad=i_pad, h_pad=h_pad,
+                              dtype=dtype, compute_unit=compute_unit))
+    in_specs = [_slab(a) for a in inputs]
     if use_cpl:
-        in_specs.append(
-            pl.BlockSpec((i_pad, i_pad), lambda g, t, *m: (0, 0)))  # shared
+        in_specs.append(_whole(cplp))          # shared by every lane block
         inputs.append(cplp)
     in_specs += [
         pl.BlockSpec((i_pad, s_block), lambda g, t, *m: (0, g)),   # x0
@@ -752,6 +784,7 @@ def chaotic_ann_gang_bits_pallas(w1, b1, w2, b2, x0, core_map, word_offset=0,
             jax.ShapeDtypeStruct((n_rows, s_total), jnp.uint32),
             jax.ShapeDtypeStruct((i_pad, s_total), dtype),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*scalars, *inputs)
 
@@ -759,18 +792,18 @@ def chaotic_ann_gang_bits_pallas(w1, b1, w2, b2, x0, core_map, word_offset=0,
 
 
 # ---------------------------------------------------------------------------
-# Sublane-stacked gang variant: C equal-shape pools, ONE grid cell per
+# Stacked gang variant: C equal-shape pools, ONE grid cell per
 # (lane-block, time-block) — the whole group's update is a single set of
-# vector ops on C-times-taller vregs.
+# vector ops, with the cores on the sublane axis.
 # ---------------------------------------------------------------------------
 
 
-def _stacked_fold16(x, n_cores: int, i_pad: int, i_dim: int):
-    """Fold the live dims of every core at once: (C*I_pad, s) -> (C, s).
+def _stacked_fold16(x, i_dim: int):
+    """Fold the live dims of every core at once: (I, C, s) -> (C, s).
 
-    Strided sublane slices pick dimension ``i`` of every core in one op, so
-    the fold stays one XOR chain of (C, s) values — the same low-mantissa
-    bits, shifts, and order per lane as ``_fold16`` on each core alone.
+    ``x[i]`` is dimension ``i`` of every core, so the fold stays one XOR
+    chain of (C, s) values — the same low-mantissa bits, shifts, and order
+    per lane as ``_fold16`` on each core alone.
     """
     if x.dtype.itemsize == 2:
         u = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
@@ -778,62 +811,58 @@ def _stacked_fold16(x, n_cores: int, i_pad: int, i_dim: int):
     else:
         u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
         lo = u & jnp.uint32(0xFFFF)
-    folded = lo[0::i_pad, :]
+    folded = lo[0]
     for i in range(1, i_dim):
-        folded = folded ^ (lo[i::i_pad, :] << jnp.uint32(5 * i % 16))
+        folded = folded ^ (lo[i] << jnp.uint32(5 * i % 16))
     return folded
 
 
 def _make_stacked_step(w1t, b1s, w2t, b2s, *, activation: str,
-                       n_cores: int, i_pad: int, h_pad: int,
                        i_dim: int, h_dim: int, lattice=None):
-    """Whole-group oscillator update on sublane-stacked state.
+    """Whole-group oscillator update on dimension-major stacked state.
 
-    x: (C*I_pad, s) — core c's state occupies sublane rows
-    [c*I_pad, c*I_pad + I).  Weight tables are pre-broadcast outside the
-    kernel: w1t[i] is the (C*H_pad, 1) column of every core's w1[:, i, :],
-    so step ``h += w1t[i] * x[i of every core]`` is ONE fused
-    multiply-add over the stacked group — same accumulation order per lane
-    as the per-core VPU path, hence bit-identical words.
+    x: (I, C, s) — ``x[i]`` holds dimension ``i`` of all C cores, one core
+    per sublane row.  Weight tables are laid out outside the kernel:
+    w1t[i] is (H, C, 1) with w1t[i, j, c] = w1[c, i, j], so
+    ``h += w1t[i] * x[i]`` is ONE multiply-add over the whole group, with
+    only leading-axis indexing and lane broadcasts (no sublane gathers) —
+    the same accumulation order per lane as the per-core VPU path, hence
+    bit-identical words.
 
-    Lattice groups reuse ``_lattice_delta`` unchanged: with the enforced
-    ``i_pad == i_dim`` the stacked state is exactly C back-to-back lattice
-    periods, so the node-index iota is core-periodic and the wrapped rolls
-    add each core's own neighbour rows — the identical jnp expression (and
+    Lattice groups reuse ``_lattice_delta``: the node index lives on the
+    leading axis, so its wrapped rolls move whole (C, s) slabs and add
+    each core's own neighbour rows — the identical jnp expression (and
     values) as the solo kernel, keeping the gang bit-identical per lane.
     """
     phi = _activation(activation)
 
     def one_step(x):
-        h = jnp.zeros((n_cores * h_pad, x.shape[1]), x.dtype)
+        h = jnp.zeros((h_dim,) + x.shape[1:], x.dtype)
         for i in range(i_dim):
-            xi = jnp.repeat(x[i::i_pad, :], h_pad, axis=0)
-            h = h + w1t[i] * xi
+            h = h + w1t[i] * x[i][None]
         h = phi(h + b1s)
         y = jnp.zeros_like(x)
         for j in range(h_dim):
-            hj = jnp.repeat(h[j::h_pad, :], i_pad, axis=0)
-            y = y + w2t[j] * hj
+            y = y + w2t[j] * h[j][None]
         y = y + b2s
         if lattice is not None:
             y = y + _lattice_delta(x, lattice)
-        return y
+        return _round_half(y, y.dtype)
 
     return one_step
 
 
 def _gang_stacked_kernel(*refs, t_block: int, unroll: int,
-                         activation: str, n_cores: int, i_pad: int,
-                         h_pad: int, i_dim: int, h_dim: int, ragged: bool,
-                         lattice):
+                         activation: str, i_dim: int, h_dim: int,
+                         ragged: bool, lattice):
     """One (lane-block, time-block) cell computing ALL C cores at once.
 
     Ragged variant: an extra (C, 1) row-map input freezes a core's state
     once its own word-row demand is met — the stacked FMA sweep still
-    spans the whole group (the sublane stack is one fused op), but a
-    frozen core's state stops advancing at exactly its demand, so its
-    final state (and word prefix) is bit-identical to a per-core launch
-    of that many rows.  Word rows past a core's demand are garbage.
+    spans the whole group (the stack is one fused op), but a frozen
+    core's state stops advancing at exactly its demand, so its final
+    state (and word prefix) is bit-identical to a per-core launch of that
+    many rows.  Word rows past a core's demand are garbage.
     """
     if ragged:
         (w1t_ref, b1_ref, w2t_ref, b2_ref, x0_ref, off_ref, rmap_ref,
@@ -851,24 +880,21 @@ def _gang_stacked_kernel(*refs, t_block: int, unroll: int,
 
     one_step = _make_stacked_step(
         w1t_ref[...], b1_ref[...], w2t_ref[...], b2_ref[...],
-        activation=activation, n_cores=n_cores, i_pad=i_pad, h_pad=h_pad,
-        i_dim=i_dim, h_dim=h_dim, lattice=lattice)
+        activation=activation, i_dim=i_dim, h_dim=h_dim, lattice=lattice)
     offs = off_ref[...]
     rmap = rmap_ref[...] if ragged else None
 
     def one_row(x, r):
         x1 = one_step(x)
         x2 = one_step(x1)
-        word = ((_stacked_fold16(x1, n_cores, i_pad, i_dim)
-                 << jnp.uint32(16))
-                | _stacked_fold16(x2, n_cores, i_pad, i_dim))
+        word = ((_stacked_fold16(x1, i_dim) << jnp.uint32(16))
+                | _stacked_fold16(x2, i_dim))
         row_idx = offs + (t * rows_per_block + r).astype(jnp.uint32)
         word = word ^ (row_idx * jnp.uint32(_GOLDEN))
         words_ref[pl.ds(r, 1), :, :] = _finalize(word)[None]
         if ragged:
             alive = (t * rows_per_block + r) < rmap          # (C, 1) bool
-            keep = jnp.repeat(alive, i_pad, axis=0)          # core-major
-            x2 = jnp.where(keep, x2, x)
+            x2 = jnp.where(alive[None], x2, x)
         return x2
 
     def chunk(x, base):
@@ -902,13 +928,14 @@ def chaotic_ann_gang_stacked_pallas(w1, b1, w2, b2, x0, word_offset=0,
 
     Where ``chaotic_ann_gang_bits_pallas`` concatenates pools along the
     lane axis (one grid cell per member lane block), this variant exploits
-    equal pool shapes to stack the group along the *sublane* axis: state is
-    (C * I_pad, s_block) in one grid cell, and each update step is ONE
-    broadcast-FMA sweep over the stacked group — C networks advance for the
-    per-cell cost of one.  This is the paper's parallelism-P MAC array
-    applied across *cores* instead of across streams, and it is what makes
-    small gang flushes cheaper than C small per-core flushes (per-launch
-    and per-grid-cell overheads are paid once, not C times).
+    equal pool shapes to stack the group: state is (I, C, s_block) in one
+    grid cell — dimension-major, the C cores on sublanes — and each update
+    step is ONE broadcast-FMA sweep over the stacked group — C networks
+    advance for the per-cell cost of one.  This is the paper's
+    parallelism-P MAC array applied across *cores* instead of across
+    streams, and it is what makes small gang flushes cheaper than C small
+    per-core flushes (per-launch and per-grid-cell overheads are paid
+    once, not C times).
 
     Per lane the FMA accumulation order, bit fold, and whitening are
     identical to the per-core kernel, so words and final states are
@@ -920,8 +947,8 @@ def chaotic_ann_gang_stacked_pallas(w1, b1, w2, b2, x0, word_offset=0,
       word_offset: scalar or (C, S) uint32 per-lane word-row offsets.
       row_map: optional (C,) int array of per-core word-row demands.  The
         stacked sweep still advances the whole group together (no FMA
-        saved — the sublane stack is one fused op), but core ``c``'s state
-        is frozen after exactly ``row_map[c]`` rows, so its final state and
+        saved — the stack is one fused op), but core ``c``'s state is
+        frozen after exactly ``row_map[c]`` rows, so its final state and
         its ``words[:row_map[c]]`` prefix are bit-identical to a per-core
         launch of ``2 * row_map[c]`` steps; later word rows are garbage.
         None = every core computes all rows (the padded group-max launch).
@@ -940,62 +967,46 @@ def chaotic_ann_gang_stacked_pallas(w1, b1, w2, b2, x0, word_offset=0,
     dtype = x0.dtype
     t_block, unroll = _bits_blocks(n_steps, t_block, unroll)
 
-    i_pad = _pad_to(max(i_dim, 1), SUBLANES)
-    h_pad = _pad_to(max(h_dim, 1), SUBLANES)
     s_pad = _pad_to(s_total, s_block)
     n_rows = n_steps // 2
     if lattice is not None:
-        _check_lattice(lattice, i_dim, i_pad)
+        _check_lattice(lattice, i_dim, i_dim)
 
-    # Pre-broadcast weight tables: w1t[i] (C*H_pad, 1) holds w1[c, i, j] at
-    # row c*H_pad + j; w2t[j] (C*I_pad, 1) holds w2[c, j, i'] at c*I_pad+i'.
-    w1t = jnp.zeros((i_dim, n_cores * h_pad, 1), dtype)
-    w1t = w1t.at[:, :, 0].set(
-        jnp.pad(w1.astype(dtype), ((0, 0), (0, 0), (0, h_pad - h_dim)))
-        .transpose(1, 0, 2).reshape(i_dim, n_cores * h_pad))
-    b1s = jnp.zeros((n_cores * h_pad, 1), dtype).at[:, 0].set(
-        jnp.pad(b1.astype(dtype), ((0, 0), (0, h_pad - h_dim))).reshape(-1))
-    w2t = jnp.zeros((h_dim, n_cores * i_pad, 1), dtype)
-    w2t = w2t.at[:, :, 0].set(
-        jnp.pad(w2.astype(dtype), ((0, 0), (0, 0), (0, i_pad - i_dim)))
-        .transpose(1, 0, 2).reshape(h_dim, n_cores * i_pad))
-    b2s = jnp.zeros((n_cores * i_pad, 1), dtype).at[:, 0].set(
-        jnp.pad(b2.astype(dtype), ((0, 0), (0, i_pad - i_dim))).reshape(-1))
-    # (C, S, I) -> (C*I_pad, S_pad): core-major sublane stacking.
-    x0p = jnp.zeros((n_cores, i_pad, s_pad), dtype).at[
-        :, :i_dim, :s_total].set(x0.transpose(0, 2, 1).astype(dtype))
-    x0p = x0p.reshape(n_cores * i_pad, s_pad)
+    # Weight tables with the core on the sublane axis: w1t[i, j, c, 0] =
+    # w1[c, i, j], b1s[j, c, 0] = b1[c, j]; likewise w2t and b2s.
+    w1t = w1.astype(dtype).transpose(1, 2, 0)[..., None]
+    b1s = b1.astype(dtype).T[..., None]
+    w2t = w2.astype(dtype).transpose(1, 2, 0)[..., None]
+    b2s = b2.astype(dtype).T[..., None]
+    # (C, S, I) -> (I, C, S_pad): dimension-major, streams on lanes.
+    x0p = jnp.zeros((i_dim, n_cores, s_pad), dtype).at[
+        :, :, :s_total].set(x0.transpose(2, 0, 1).astype(dtype))
     off = jnp.asarray(word_offset, jnp.uint32)
     offp = jnp.zeros((n_cores, s_pad), jnp.uint32).at[:, :s_total].set(
         jnp.broadcast_to(off, (n_cores, s_total)))
     ragged = row_map is not None
 
-    in_specs = [
-        pl.BlockSpec((i_dim, n_cores * h_pad, 1),
-                     lambda s, t: (0, 0, 0)),                 # w1t
-        pl.BlockSpec((n_cores * h_pad, 1), lambda s, t: (0, 0)),
-        pl.BlockSpec((h_dim, n_cores * i_pad, 1),
-                     lambda s, t: (0, 0, 0)),                 # w2t
-        pl.BlockSpec((n_cores * i_pad, 1), lambda s, t: (0, 0)),
-        pl.BlockSpec((n_cores * i_pad, s_block),
-                     lambda s, t: (0, s)),                    # x0
+    inputs = [w1t, b1s, w2t, b2s]
+    in_specs = [_whole(a) for a in inputs]
+    in_specs += [
+        pl.BlockSpec((i_dim, n_cores, s_block),
+                     lambda s, t: (0, 0, s)),                 # x0
         pl.BlockSpec((n_cores, s_block), lambda s, t: (0, s)),  # offsets
     ]
-    inputs = [w1t, b1s, w2t, b2s, x0p, offp]
+    inputs += [x0p, offp]
     if ragged:
         if np.shape(row_map) != (n_cores,):
             raise ValueError(f"row_map must have shape ({n_cores},), got "
                              f"{np.shape(row_map)}")
         rmapp = jnp.minimum(jnp.asarray(row_map, jnp.int32),
                             n_rows).reshape(n_cores, 1)
-        in_specs.append(pl.BlockSpec((n_cores, 1), lambda s, t: (0, 0)))
+        in_specs.append(_whole(rmapp))
         inputs.append(rmapp)
 
     grid = (s_pad // s_block, n_steps // t_block)
     words, state = pl.pallas_call(
         functools.partial(_gang_stacked_kernel, t_block=t_block,
                           unroll=unroll, activation=activation,
-                          n_cores=n_cores, i_pad=i_pad, h_pad=h_pad,
                           i_dim=i_dim, h_dim=h_dim, ragged=ragged,
                           lattice=lattice),
         grid=grid,
@@ -1003,18 +1014,18 @@ def chaotic_ann_gang_stacked_pallas(w1, b1, w2, b2, x0, word_offset=0,
         out_specs=[
             pl.BlockSpec((t_block // 2, n_cores, s_block),
                          lambda s, t: (t, 0, s)),
-            pl.BlockSpec((n_cores * i_pad, s_block), lambda s, t: (0, s)),
+            pl.BlockSpec((i_dim, n_cores, s_block), lambda s, t: (0, 0, s)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_rows, n_cores, s_pad), jnp.uint32),
-            jax.ShapeDtypeStruct((n_cores * i_pad, s_pad), dtype),
+            jax.ShapeDtypeStruct((i_dim, n_cores, s_pad), dtype),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
     )(*inputs)
 
     words = words[:, :, :s_total]
-    state = state.reshape(n_cores, i_pad, s_pad)[
-        :, :i_dim, :s_total].transpose(0, 2, 1)
+    state = state[:, :, :s_total].transpose(1, 2, 0)
     return words, state
 
 
@@ -1113,7 +1124,6 @@ def _sharded_gang_bits_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
     static kernel config).  Weights/pool/maps are traced arguments, so
     jit retraces only when a launch SHAPE is new — per-flush weight or
     demand values hit the compiled program."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     kw = dict(n_steps=n_steps, s_block=s_block, t_block=t_block,
@@ -1134,10 +1144,10 @@ def _sharded_gang_bits_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
         return chaotic_ann_gang_bits_pallas(
             w1, b1, w2, b2, x_l, cmap_l, off_l, rmap_l, cpl, **kw)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=(P(None, mesh_axis), P(mesh_axis, None)),
-        check_rep=False))
+        check_vma=False))
 
 
 def chaotic_ann_gang_stacked_sharded(w1, b1, w2, b2, x0, word_offset=0,
@@ -1183,7 +1193,6 @@ def _sharded_gang_stacked_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
                              lattice, interpret):
     """Jitted shard_map'd sublane-stacked gang launch, cached per (mesh,
     static kernel config) — see ``_sharded_gang_bits_fn``."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     kw = dict(n_steps=n_steps, s_block=s_block, t_block=t_block,
@@ -1202,7 +1211,7 @@ def _sharded_gang_stacked_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
         def local(w1, b1, w2, b2, x_l, off_l):
             return chaotic_ann_gang_stacked_pallas(
                 w1, b1, w2, b2, x_l, off_l, None, **kw)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=(P(None, None, mesh_axis), P(None, mesh_axis, None)),
-        check_rep=False))
+        check_vma=False))

@@ -5,6 +5,7 @@ compiled on TPU) or the pure-jnp reference, with a uniform (S, I) API.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -22,7 +23,48 @@ from repro.kernels.chaotic_ann import (chaotic_ann_bits_pallas,
                                        gang_effective_rows,
                                        gang_partition_maps)
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
+BACKENDS = ("pallas", "pallas_interpret", "ref")
+
+
+@functools.lru_cache(maxsize=None)
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def resolve_backend(backend: str = "auto") -> str:
+    """The backend a launch with ``backend`` runs on — the one place
+    ``"auto"`` is decided: the compiled Pallas kernel (``"pallas"``) when
+    JAX's default backend is a TPU, the Pallas interpreter otherwise.
+    Explicit backends pass through."""
+    if backend == "auto":
+        return "pallas" if _on_tpu() else "pallas_interpret"
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; expected 'auto' or "
+                         f"one of {BACKENDS}")
+    return backend
+
+
+def is_build_error(e: BaseException) -> bool:
+    """Whether ``e`` was raised while building a kernel launch — tracing,
+    lowering or compiling it — rather than while running it.
+
+    Building is deterministic: a retry fails the same way, so such an
+    error is a fault of the program, never a transient fault of a core.
+    Tracing and lowering raise Python errors (a Pallas TPU lowering gap
+    is a ``NotImplementedError``, a refused shape a ``ValueError``,
+    Mosaic's own checks a ``MosaicError``/``LoweringException``); the
+    TPU compiler raises a ``JaxRuntimeError`` that names Mosaic or the
+    VMEM it ran out of.
+    """
+    if isinstance(e, (NotImplementedError, TypeError, ValueError,
+                      IndexError)):
+        return True
+    if {c.__name__ for c in type(e).__mro__} & {"MosaicError",
+                                                "LoweringException"}:
+        return True
+    msg = str(e)
+    return (isinstance(e, jax.errors.JaxRuntimeError)
+            and any(k in msg for k in ("Mosaic", "vmem", "VMEM")))
 
 
 def _kernel_kwargs(config) -> Dict[str, object]:
@@ -59,8 +101,8 @@ def chaotic_trajectory(params: Dict[str, jax.Array], x0: jax.Array, n_steps: int
                        compute_unit: str = "vpu", config=None) -> jax.Array:
     """Generate (n_steps, S, I) oscillator trajectories.
 
-    backend: 'auto' | 'pallas' | 'pallas_interpret' | 'ref'.
-    'auto' uses the compiled Pallas kernel on TPU and interpret mode on CPU.
+    backend: 'auto' | 'pallas' | 'pallas_interpret' | 'ref'; 'auto' is
+    decided by ``resolve_backend``.
     config: optional ``repro.core.dse.Candidate`` — when given, overrides the
     explicit (s_block, t_block, unroll, compute_unit) arguments so the DSE
     output drives the kernel instantiation.
@@ -77,7 +119,7 @@ def chaotic_trajectory(params: Dict[str, jax.Array], x0: jax.Array, n_steps: int
                 w1, b1, w2, b2, x0, n_steps, activation, lattice=lattice,
                 coupling=cpl, compute_unit=kw["compute_unit"])
         return ref.chaotic_ann_ref(w1, b1, w2, b2, x0, n_steps, activation)
-    interpret = (backend == "pallas_interpret") or (backend == "auto" and not _ON_TPU)
+    interpret = resolve_backend(backend) == "pallas_interpret"
     return chaotic_ann_pallas(
         w1, b1, w2, b2, x0, cpl, n_steps=n_steps, activation=activation,
         lattice=lattice, interpret=interpret, **kw)
@@ -112,7 +154,7 @@ def chaotic_bits(params: Dict[str, jax.Array], x0: jax.Array, n_steps: int,
             traj = ref.chaotic_ann_ref(w1, b1, w2, b2, x0, n_steps,
                                        activation)
         return pack_words(traj, word_offset), traj[-1]
-    interpret = (backend == "pallas_interpret") or (backend == "auto" and not _ON_TPU)
+    interpret = resolve_backend(backend) == "pallas_interpret"
     return chaotic_ann_bits_pallas(
         w1, b1, w2, b2, x0, word_offset, cpl, n_steps=n_steps,
         activation=activation, lattice=lattice, interpret=interpret, **kw)
@@ -199,7 +241,7 @@ def chaotic_bits_gang(params: Dict[str, jax.Array], x0: jax.Array,
             state_parts.append(traj[-1])
         return (jnp.concatenate(words_parts, axis=1),
                 jnp.concatenate(state_parts, axis=0))
-    interpret = (backend == "pallas_interpret") or (backend == "auto" and not _ON_TPU)
+    interpret = resolve_backend(backend) == "pallas_interpret"
     rmap = None if row_map is None else jnp.asarray(row_map, jnp.int32)
     if mesh is not None and int(mesh.shape[mesh_axis]) > 1:
         n_dev = int(mesh.shape[mesh_axis])
@@ -300,7 +342,7 @@ def chaotic_bits_gang_stacked(params: Dict[str, jax.Array], x0: jax.Array,
             state_parts.append(traj[-1])
         return (jnp.stack(words_parts, axis=1),
                 jnp.stack(state_parts, axis=0))
-    interpret = (backend == "pallas_interpret") or (backend == "auto" and not _ON_TPU)
+    interpret = resolve_backend(backend) == "pallas_interpret"
     rmap = None if row_map is None else jnp.asarray(row_map, jnp.int32)
     if mesh is not None and int(mesh.shape[mesh_axis]) > 1:
         return chaotic_ann_gang_stacked_sharded(

@@ -19,10 +19,13 @@ def chaotic_ann_ref(w1: Array, b1: Array, w2: Array, b2: Array,
     """
     phi = {"relu": jax.nn.relu, "tanh": jnp.tanh, "sigmoid": jax.nn.sigmoid}[activation]
     dtype = x0.dtype
+    # exact f32 products on a TPU too, whose default f32 dot is one bf16 pass
+    prec = jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
     def step(x, _):
-        h = phi(x @ w1.astype(dtype) + b1.astype(dtype))
-        y = h @ w2.astype(dtype) + b2.astype(dtype)
+        h = phi(jnp.matmul(x, w1.astype(dtype), precision=prec)
+                + b1.astype(dtype))
+        y = jnp.matmul(h, w2.astype(dtype), precision=prec) + b2.astype(dtype)
         return y, y
 
     _, traj = jax.lax.scan(step, x0, None, length=n_steps)
@@ -52,7 +55,8 @@ def chaotic_ann_lattice_ref(w1: Array, b1: Array, w2: Array, b2: Array,
     Returns:
       (n_steps, S, I) trajectory (excluding x0), in x0's dtype.
     """
-    from repro.kernels.chaotic_ann import _check_lattice, _make_step
+    from repro.kernels.chaotic_ann import (_check_lattice, _make_step,
+                                           _pad_params)
     dtype = x0.dtype
     i_dim, h_dim = w1.shape
     _check_lattice(lattice, i_dim, i_dim)
@@ -62,8 +66,8 @@ def chaotic_ann_lattice_ref(w1: Array, b1: Array, w2: Array, b2: Array,
             raise ValueError("mxu lattice oracle needs the coupling operand")
         cpl = coupling.astype(dtype)
     step = _make_step(
-        w1.astype(dtype), b1.astype(dtype).reshape(-1, 1),
-        w2.astype(dtype), b2.astype(dtype).reshape(-1, 1),
+        *_pad_params(w1, b1, w2, b2, i_pad=i_dim, h_pad=h_dim, dtype=dtype,
+                     compute_unit=compute_unit),
         activation=activation, compute_unit=compute_unit,
         i_dim=i_dim, h_dim=h_dim, lattice=lattice, cpl=cpl)
 

@@ -9,12 +9,8 @@ import jax
 
 
 def _make_mesh(shape, axes, devices):
-    # jax >= 0.5 takes an axis_types positional; 0.4.x does not have AxisType.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, (axis_type.Auto,) * len(axes),
-                             devices=devices)
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, (jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

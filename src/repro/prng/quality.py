@@ -66,18 +66,26 @@ def nist_gate(system: str, dtype: str = "float32", *,
     eng = ChaoticPRNG(params, n_streams=n_streams, backend=backend,
                       dtype=jnp.dtype(dtype))
     words, _ = eng.next_words(eng.init(seed=seed), n_words)
+    return {"system": system, "dtype": str(jnp.dtype(dtype)),
+            **nist_verdict(words, alpha=alpha)}
+
+
+def nist_verdict(words: np.ndarray, *,
+                 alpha: float = GATE_ALPHA) -> Dict[str, object]:
+    """The offline gate's verdict on words already in hand (for example,
+    words a farm core served): the full NIST subset, ``quarantined`` when
+    more tests fail than chance explains or any fails hard."""
+    words = np.asarray(words, np.uint32).reshape(-1)
     res = run_nist_subset(words, alpha=alpha)
     failed = sorted(k for k, v in res.items() if not v["passed"])
     hard_failed = sorted(k for k, v in res.items()
                          if v["p_value"] < ALPHA_HARD)
-    quarantine = len(failed) > MAX_CHANCE_FAILS or bool(hard_failed)
     return {
-        "system": system, "dtype": str(jnp.dtype(dtype)),
-        "n_words": int(n_words),
+        "n_words": int(words.size),
         "failed_tests": failed, "hard_failed_tests": hard_failed,
         "p_values": {k: v["p_value"] for k, v in res.items()},
         "passed": not failed,
-        "quarantined": quarantine,
+        "quarantined": len(failed) > MAX_CHANCE_FAILS or bool(hard_failed),
     }
 
 

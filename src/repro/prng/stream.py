@@ -354,6 +354,22 @@ def weights_dir() -> pathlib.Path:
     return pathlib.Path(__file__).resolve().parents[3] / "results" / "weights"
 
 
+def registry_weights(system: str) -> Optional[Dict[str, np.ndarray]]:
+    """The committed registry bundle of a (scalar) system, or ``None``
+    when its file is missing, torn, or stamped by another training recipe
+    or jax version — the entries ``trained_oscillator`` would retrain."""
+    path = weights_dir() / f"{system}.npz"
+    try:
+        bundle = {k: np.asarray(v) for k, v in np.load(path).items()}
+    except (OSError, ValueError, EOFError, zipfile.BadZipFile):
+        return None
+    stamp = bundle.pop(_FINGERPRINT_KEY, None)
+    if (set(_BUNDLE_KEYS) <= set(bundle) and stamp is not None
+            and str(stamp) == registry_fingerprint(system)):
+        return bundle
+    return None
+
+
 def trained_oscillator(system: str = "chen", seed: int = 0
                        ) -> Dict[str, np.ndarray]:
     """Trained weights + normalizer for any registered chaotic system.
@@ -384,20 +400,15 @@ def trained_oscillator(system: str = "chen", seed: int = 0
             offset=np.tile(base["offset"], n_nodes))
         _WEIGHTS_CACHE[key] = bundle
         return bundle
-    fingerprint = registry_fingerprint(system)
-    path = weights_dir() / f"{system}.npz"
-    if seed == 0 and path.exists():
-        try:
-            bundle = {k: np.asarray(v) for k, v in np.load(path).items()}
-        except (OSError, ValueError, EOFError, zipfile.BadZipFile):
-            bundle = {}                  # torn/corrupt cache: retrain below
-        stamp = bundle.pop(_FINGERPRINT_KEY, None)
-        if set(_BUNDLE_KEYS) <= set(bundle) and (
-                stamp is not None and str(stamp) == fingerprint):
+    if seed == 0:
+        bundle = registry_weights(system)
+        if bundle is not None:
             _WEIGHTS_CACHE[key] = bundle
             return bundle
-        # Unstamped or stale entry (recipe/jax changed since training):
-        # fall through to retrain rather than serve stale weights.
+        # Missing, torn or stale entry (recipe/jax changed since
+        # training): retrain rather than serve stale weights.
+    fingerprint = registry_fingerprint(system)
+    path = weights_dir() / f"{system}.npz"
     from repro.core.ann import AnnConfig, extract_parameters, train
     from repro.core.chaotic import get_system, make_dataset
     sys_ = get_system(system)           # fail fast on unknown system names

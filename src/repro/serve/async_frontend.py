@@ -92,6 +92,7 @@ from typing import Deque, Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
+from repro.kernels.ops import is_build_error
 from repro.serve.admission import AdmissionController
 from repro.serve.clock import Clock, SystemClock
 from repro.serve.farm import OscillatorFarm
@@ -677,7 +678,10 @@ class AsyncOscillatorFarm:
         mid-cycle: its batch requests fail with ``CoreQuarantined``, the
         gang re-plans without it, and the remaining batch retries with a
         fresh budget.  Without ``health=`` the first failure propagates
-        (the pre-supervision behavior).
+        (the pre-supervision behavior), and so does, always, an error
+        raised while tracing, lowering or compiling the launch
+        (``ops.is_build_error``): every retry would fail the same way, so
+        it is a fault of the program, never of a core.
         """
         health = self.health
         attempt = 0
@@ -686,7 +690,7 @@ class AsyncOscillatorFarm:
                 await self._launch(slo_by_core)
             # repro: allow[broad-except] reason=supervision seam: ANY launch failure is retried/attributed here; without health= it reraises unchanged
             except Exception as e:
-                if health is None:
+                if health is None or is_build_error(e):
                     raise
                 failed = sorted(set(getattr(e, "cores", ()))
                                 or {r.core for r in batch})

@@ -142,6 +142,7 @@ class GangScheduler:
         self.planner = bool(planner)
         self.cost_model = cost_model or GangCostModel()
         self.decisions = {"padded": 0, "ragged": 0, "split": 0}
+        self.layouts = {"stacked": 0, "concat": 0}   # gang launches by layout
         # flushes where an SLO class actually constrained the choice set
         self.slo_forced = {"latency": 0, "bulk": 0}
         self.profile: Optional[Dict[str, float]] = None
@@ -428,6 +429,7 @@ class GangScheduler:
                           for ci, (start, live, _) in enumerate(plan["spans"])]
         plan["last_x"], plan["handed"] = state, handed
         self.launches += 1
+        self.layouts[layout] += 1
         # ragged and padded launches of the same shape are distinct jit
         # traces (row_map None vs array), hence distinct dispatch keys
         self._dispatch_keys.add((plan["sig"], n_rows, bool(ragged)))
@@ -900,6 +902,11 @@ class OscillatorFarm:
         """Executed planner decisions so far, by kind
         (padded / ragged / split)."""
         return dict(self._sched.decisions)
+
+    @property
+    def layout_launches(self) -> Dict[str, int]:
+        """Gang launches so far, by layout (stacked / concat)."""
+        return dict(self._sched.layouts)
 
     @property
     def slo_forced(self) -> Dict[str, int]:

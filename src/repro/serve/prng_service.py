@@ -64,7 +64,7 @@ class PRNGService:
         self.lanes_per_client = int(lanes_per_client)
         self.burn_in = effective_burn_in(burn_in)
         self.activation = activation
-        self.backend = backend
+        self.backend = ops.resolve_backend(backend)   # never 'auto'
         # Kernel compute dtype: f32 unless serving a half-width (bf16) core.
         self.dtype = jnp.dtype(dtype) if dtype is not None else jnp.float32
         if config is None:

@@ -6,7 +6,7 @@ mesh=)`` / ``shard_stream_pool``): a direct ``shard_map`` here bypasses
 the gang scheduler, the cost model, and the topology-keyed plan caches,
 and its words sit outside every bit-identity suite.
 """
-from jax.experimental.shard_map import shard_map
+import jax
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ops
@@ -16,6 +16,6 @@ def launch_sharded(params, x0, n_steps, mesh):
     def local(x_l):
         return ops.chaotic_bits(params, x_l, n_steps, 0)
 
-    fn = shard_map(local, mesh=mesh, in_specs=(P("data", None),),
-                   out_specs=(P(None, "data"), P("data", None)))
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(P("data", None),),
+                       out_specs=(P(None, "data"), P("data", None)))
     return fn(x0)

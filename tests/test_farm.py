@@ -158,3 +158,35 @@ def test_farm_snapshot_restore_with_pending(farm_dir):
         backend="pallas_interpret")
     with pytest.raises(ValueError):
         extra.restore(snap)                       # ...and none beyond them
+
+
+def test_committed_bf16_farm_stacked_equals_solo():
+    """The committed bf16 3-8 cores at their served blocking (t_block
+    256, unroll 8) ride one stacked gang launch, padded and ragged; every
+    tenant's words equal the per-core path's, bit for bit."""
+    committed = (pathlib.Path(__file__).resolve().parents[1]
+                 / "results" / "generated_cores" / "farm")
+    cores = ("chen", "chua", "lorenz", "rossler")
+    farms = {gang: OscillatorFarm.from_generated(
+        committed, cores=cores, gang=gang, backend="pallas_interpret")
+        for gang in (True, False)}
+    for farm in farms.values():
+        for ci, core in enumerate(cores):
+            for t in range(2):
+                farm.register(core, f"t{t}", seed=10 * ci + t)
+    out = {}
+    for gang, farm in farms.items():
+        for rows in ((32, 32, 32, 32), (32, 8, 16, 8)):
+            for core, n in zip(cores, rows):
+                for t in range(2):
+                    farm.request(core, f"t{t}", 128 * n)
+            res = farm.flush()
+            for core in cores:
+                for t in range(2):
+                    out.setdefault((gang, core, t), []).append(
+                        res[core][f"t{t}"])
+    assert farms[True].layout_launches["stacked"] >= 1
+    for core in cores:
+        for t in range(2):
+            for a, b in zip(out[(True, core, t)], out[(False, core, t)]):
+                np.testing.assert_array_equal(a, b)

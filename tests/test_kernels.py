@@ -118,3 +118,46 @@ def test_bits_deterministic_and_balanced():
     np.testing.assert_array_equal(np.asarray(bits1), np.asarray(bits2))
     ones = np.unpackbits(np.asarray(bits1).view(np.uint8)).mean()
     assert abs(ones - 0.5) < 0.02
+
+
+def _rounding_cases(kind):
+    """f32 bit patterns: random words, exact and near ties of both parities
+    of the kept bit, and the specials (+-0, +-inf, NaNs with payloads in
+    the dropped bits, subnormals, the largest finites that round to inf)."""
+    rng = np.random.default_rng(0)
+    if kind == "random":
+        return rng.integers(0, 2 ** 32, 100_000, dtype=np.uint64).astype(
+            np.uint32)
+    if kind == "ties":
+        hi = rng.integers(0, 2 ** 16, 4096, dtype=np.uint32) << 16
+        return np.concatenate([hi | np.uint32(lo) for lo in
+                               (0x8000, 0x7FFF, 0x8001, 0x0000, 0xFFFF)])
+    return np.array([0x00000000, 0x80000000, 0x7F800000, 0xFF800000,
+                     0x7FC00000, 0xFFC00000, 0x7F800001, 0x7F808000,
+                     0x7FFFFFFF, 0xFFFF8000, 0x00000001, 0x00008000,
+                     0x00018000, 0x007FFFFF, 0x007F8000, 0x80008000,
+                     0x00800000, 0x7F7FFFFF, 0x7F7F8000, 0xFF7FFFFF],
+                    np.uint32)
+
+
+@pytest.mark.parametrize("kind", ["random", "ties", "specials"])
+def test_round_bf16_bits_equals_reduce_precision(kind):
+    """The integer bf16 rounding the kernels use in place of
+    ``reduce_precision`` (which Mosaic cannot lower) equals it bit for
+    bit, in the kernel's own (jitted) and in eager form."""
+    from repro.kernels.chaotic_ann import round_bf16_bits
+    v = jnp.asarray(_rounding_cases(kind).view(np.float32))
+    want = np.asarray(jax.lax.reduce_precision(v, 8, 7)).view(np.uint32)
+    for fn in (round_bf16_bits, jax.jit(round_bf16_bits)):
+        got = np.asarray(fn(v)).view(np.uint32)
+        np.testing.assert_array_equal(got, want)
+
+
+def test_resolve_backend_decides_auto_once():
+    from repro.kernels.ops import resolve_backend
+    want = "pallas" if jax.default_backend() == "tpu" else "pallas_interpret"
+    assert resolve_backend() == resolve_backend("auto") == want
+    for b in ("pallas", "pallas_interpret", "ref"):
+        assert resolve_backend(b) == b
+    with pytest.raises(ValueError, match="unknown backend"):
+        resolve_backend("interpret")

@@ -275,6 +275,34 @@ def test_retry_budget_exhausted_propagates_to_futures():
     _run(go())
 
 
+@pytest.mark.parametrize("err", [
+    NotImplementedError("Unimplemented primitive in Pallas TPU lowering"),
+    ValueError("Shape mismatch in input, indices and output"),
+], ids=["lowering", "shape"])
+def test_build_error_propagates_without_retry_or_quarantine(err):
+    """An error raised while building a launch fails the same way on
+    every retry: it reaches the tenants at once, is never retried, and
+    never counts against a core's breaker."""
+    async def go():
+        fc = FakeClock()
+        health = _health(breaker_threshold=1)
+        farm = _farm(n_cores=1, clock=fc)
+
+        def refuse(*a, **kw):
+            raise err
+
+        farm.flush = refuse
+        async with AsyncOscillatorFarm(farm, clock=fc, offload=False,
+                                       health=health) as af:
+            fut = af.submit("core0", "t", 100)
+            await _drive(fc, [fut])
+            assert fut.exception() is err
+        assert health.stats["retries"] == 0
+        assert health.stats["launch_failures"] == 0
+        assert not farm.quarantined
+    _run(go())
+
+
 # ---------------------------------------------------------------------------
 # Circuit breaker: quarantine, re-planned gang, typed errors
 # ---------------------------------------------------------------------------
