@@ -89,6 +89,7 @@ from repro.core.dse import (CostModel, GangCostModel, LatencyModel,
 from repro.kernels.ops import chaotic_bits
 from repro.prng.stream import _splitmix_seeds, default_params
 from repro.serve.farm import OscillatorFarm, _compat_key
+from repro.serve.tracer import TIMERS
 
 try:
     from benchmarks.common import emit, time_fn
@@ -787,9 +788,9 @@ def _planner_section(n_streams, p, lm, cm, smoke, profile=False):
         _interleaved_flushes({"profile": farm}, group, n_clients, skewed,
                              n_iters, cold=True)
         prof = farm.profile_stats
-        n = max(prof.pop("flushes"), 1.0)
-        result["profile_ms_per_flush"] = {k: v / n * 1e3
-                                          for k, v in prof.items()}
+        n = max(prof["flushes"], 1.0)
+        result["profile_ms_per_flush"] = {k: prof[k] / n * 1e3
+                                          for k in TIMERS}
         emit("farm/planner_profile", 0.0,
              ";".join(f"{k}={v:.2f}ms"
                       for k, v in result["profile_ms_per_flush"].items()))

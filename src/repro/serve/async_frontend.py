@@ -114,6 +114,7 @@ class _Request:
     slo: Optional[str] = None
     rows_est: int = 0          # admission gauge units owed back on dequeue
     released: bool = False
+    submitted: float = 0.0     # when it was submitted, same clock
 
 
 def percentile(xs: List[float], q: float) -> float:
@@ -203,7 +204,6 @@ class AsyncOscillatorFarm:
         self._flush_lock: Optional[asyncio.Lock] = None
         self._inflight = False
         self.flushes = 0
-        self.served_words = 0
         # Ring buffers: a long-running front-end must not grow linearly in
         # served requests / failures.  deadline_stats() is windowed to the
         # stats_window most recent samples.
@@ -307,12 +307,19 @@ class AsyncOscillatorFarm:
             self.journal.record_register(
                 core, client, self.farm.services[core].clients[client].seed)
 
-    def _deadline(self, deadline_ms: Optional[float]) -> float:
+    def _request(self, core: str, client: str, n_words: int,
+                 deadline_ms: Optional[float], future: _Future,
+                 slo: Optional[str], rows_est: int) -> _Request:
+        """A draw to queue, stamped with its submit time and its absolute
+        deadline."""
         if deadline_ms is None:
             deadline_ms = self.default_deadline_ms
         if deadline_ms is None:
             deadline_ms = 0.0
-        return self.clock.now() + float(deadline_ms) / 1e3
+        now = self.clock.now()
+        return _Request(core, client, int(n_words),
+                        now + float(deadline_ms) / 1e3, future, slo=slo,
+                        rows_est=rows_est, submitted=now)
 
     def _validate(self, core: str, client: str, n_words: int,
                   slo: Optional[str]) -> None:
@@ -373,9 +380,8 @@ class AsyncOscillatorFarm:
             fut.set_result(np.empty(0, np.uint32))
             return fut
         rows_est = self._admit(core, client, n_words)
-        self._queue.append(_Request(core, client, int(n_words),
-                                    self._deadline(deadline_ms), fut,
-                                    slo=slo, rows_est=rows_est))
+        self._queue.append(self._request(core, client, n_words,
+                                         deadline_ms, fut, slo, rows_est))
         self._wake.set()
         return fut
 
@@ -422,9 +428,8 @@ class AsyncOscillatorFarm:
             cfut.set_result(np.empty(0, np.uint32))
             return cfut.result()
         rows_est = self._admit(core, client, n_words)
-        self._ingress.append(_Request(core, client, int(n_words),
-                                      self._deadline(deadline_ms), cfut,
-                                      slo=slo, rows_est=rows_est))
+        self._ingress.append(self._request(core, client, n_words,
+                                           deadline_ms, cfut, slo, rows_est))
         self._loop.call_soon_threadsafe(self._wake.set)
         try:
             return cfut.result(timeout)
@@ -569,49 +574,56 @@ class AsyncOscillatorFarm:
         commit, the batch is the ONLY demand the launch phase serves —
         requests arriving mid-launch stay queued for the next cycle.
         """
-        batch: List[_Request] = []
-        quarantined = self.farm.quarantined
-        for r in self._queue:
-            self._release(r)
-            f = r.future
-            if isinstance(f, concurrent.futures.Future):
-                if not f.set_running_or_notify_cancel():
-                    continue               # cancelled: demand rolled back
-            elif f.cancelled():
-                continue
-            if r.core in quarantined:
-                # quarantined with no standby after this request queued:
-                # its demand never enters the farm
-                f.set_exception(CoreQuarantined(
-                    f"core {r.core!r} quarantined while request was "
-                    f"queued", core=r.core, reason="quarantined"))
-                continue
-            batch.append(r)
-        self._queue = []
-        if not batch:
-            return None
-        # Words the sync surface is owed come FIRST in each client's flush
-        # output (outbox backlog, then earlier-requested service pending);
-        # record the counts so the split below can re-park them.
-        owed: Dict[Tuple[str, str], int] = {}
-        for core, svc in self.farm.services.items():
-            for name in svc.clients:
-                n = svc.pending_words(name) + svc.outbox_words(name)
-                if n:
-                    owed[(core, name)] = n
-        fifo: Dict[Tuple[str, str], List[_Request]] = {}
-        slos: Dict[str, set] = {}
-        for r in batch:
-            self.farm.services[r.core].request(r.client, r.n_words)
-            fifo.setdefault((r.core, r.client), []).append(r)
-            slos.setdefault(r.core, set()).add(r.slo)
-        slo_by_core = {}
-        for core, classes in slos.items():
-            if "latency" in classes:
-                slo_by_core[core] = "latency"
-            elif classes == {"bulk"}:
-                slo_by_core[core] = "bulk"
-        return batch, owed, fifo, slo_by_core
+        tracer = self.farm.tracer
+        with tracer.span("frontend.cycle.commit", "commit"):
+            batch: List[_Request] = []
+            quarantined = self.farm.quarantined
+            for r in self._queue:
+                self._release(r)
+                f = r.future
+                if isinstance(f, concurrent.futures.Future):
+                    if not f.set_running_or_notify_cancel():
+                        continue               # cancelled: demand rolled back
+                elif f.cancelled():
+                    continue
+                if r.core in quarantined:
+                    # quarantined with no standby after this request queued:
+                    # its demand never enters the farm
+                    f.set_exception(CoreQuarantined(
+                        f"core {r.core!r} quarantined while request was "
+                        f"queued", core=r.core, reason="quarantined"))
+                    continue
+                batch.append(r)
+            self._queue = []
+            if not batch:
+                return None
+            if tracer.on:
+                now = self.clock.now()
+                tracer.count(queue_wait_s=sum(now - r.submitted
+                                              for r in batch),
+                             draws_committed=len(batch))
+            # Words the sync surface is owed come FIRST in each client's flush
+            # output (outbox backlog, then earlier-requested service pending);
+            # record the counts so the split below can re-park them.
+            owed: Dict[Tuple[str, str], int] = {}
+            for core, svc in self.farm.services.items():
+                for name in svc.clients:
+                    n = svc.pending_words(name) + svc.outbox_words(name)
+                    if n:
+                        owed[(core, name)] = n
+            fifo: Dict[Tuple[str, str], List[_Request]] = {}
+            slos: Dict[str, set] = {}
+            for r in batch:
+                self.farm.services[r.core].request(r.client, r.n_words)
+                fifo.setdefault((r.core, r.client), []).append(r)
+                slos.setdefault(r.core, set()).add(r.slo)
+            slo_by_core = {}
+            for core, classes in slos.items():
+                if "latency" in classes:
+                    slo_by_core[core] = "latency"
+                elif classes == {"bulk"}:
+                    slo_by_core[core] = "bulk"
+            return batch, owed, fifo, slo_by_core
 
     def _resolve(self, batch: List[_Request],
                  owed: Dict[Tuple[str, str], int],
@@ -624,30 +636,32 @@ class AsyncOscillatorFarm:
         outboxes (cheap, safe on the loop thread) and its content/order
         is identical to a ``deliver=True`` flush.
         """
-        out = self.farm.flush()
-        now = self.clock.now()
-        self.flushes += 1
-        for core, per_client in out.items():
-            for client, words in per_client.items():
-                head = owed.get((core, client), 0)
-                if head:
-                    self.farm.services[core].park(client, words[:head])
-                pos = head
-                for r in fifo.pop((core, client), ()):
-                    r.future.set_result(words[pos:pos + r.n_words])
-                    pos += r.n_words
-                    self.served_words += r.n_words
-                    self._miss_ms.append(
-                        max(0.0, now - r.deadline) * 1e3)
-                if pos != len(words):
-                    raise AssertionError(
-                        f"flush word accounting broken for "
-                        f"{core}/{client}: {len(words)} words, "
-                        f"consumed {pos}")
-        if fifo:
-            raise AssertionError(
-                f"flush served no words for queued requests: "
-                f"{sorted(fifo)}")
+        tracer = self.farm.tracer
+        with tracer.span("frontend.cycle.resolve", "resolve"):
+            with tracer.span("frontend.cycle.deliver"):
+                out = self.farm.flush()
+            now = self.clock.now()
+            self.flushes += 1
+            for core, per_client in out.items():
+                for client, words in per_client.items():
+                    head = owed.get((core, client), 0)
+                    if head:
+                        self.farm.services[core].park(client, words[:head])
+                    pos = head
+                    for r in fifo.pop((core, client), ()):
+                        r.future.set_result(words[pos:pos + r.n_words])
+                        pos += r.n_words
+                        self._miss_ms.append(
+                            max(0.0, now - r.deadline) * 1e3)
+                    if pos != len(words):
+                        raise AssertionError(
+                            f"flush word accounting broken for "
+                            f"{core}/{client}: {len(words)} words, "
+                            f"consumed {pos}")
+            if fifo:
+                raise AssertionError(
+                    f"flush served no words for queued requests: "
+                    f"{sorted(fifo)}")
 
     async def _launch(self, slo_by_core: Dict[str, str]) -> None:
         """The launch phase of one flush (executor when ``offload``)."""
@@ -786,14 +800,15 @@ class AsyncOscillatorFarm:
         loop) and quarantine any core the monitor condemns."""
         if self.health is None:
             return
-        if self._offload:
-            verdicts = await self._loop.run_in_executor(
-                self._executor, self.health.evaluate)
-        else:
-            verdicts = self.health.evaluate()
-        for core, v in verdicts.items():
-            if core not in self.farm.quarantined:
-                self._quarantine(core, reason=str(v["reason"]))
+        with self.farm.tracer.span("frontend.cycle.quality"):
+            if self._offload:
+                verdicts = await self._loop.run_in_executor(
+                    self._executor, self.health.evaluate)
+            else:
+                verdicts = self.health.evaluate()
+            for core, v in verdicts.items():
+                if core not in self.farm.quarantined:
+                    self._quarantine(core, reason=str(v["reason"]))
 
     async def _flush_cycle(self) -> None:
         """ONE coalesced flush: commit (on-loop) -> launch (executor when

@@ -35,6 +35,7 @@ Cores come from two places:
 """
 from __future__ import annotations
 
+import functools
 import json
 import pathlib
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
@@ -47,6 +48,7 @@ from repro.prng.stream import _round_rows
 from repro.serve.clock import Clock, SystemClock
 from repro.serve.health import CoreQuarantined
 from repro.serve.prng_service import PRNGService
+from repro.serve.tracer import Tracer
 
 
 def _topology(svc: PRNGService) -> Optional[Tuple]:
@@ -131,9 +133,7 @@ class GangScheduler:
     """
 
     def __init__(self, cost_model: Optional[GangCostModel] = None,
-                 planner: bool = True, clock: Optional[Clock] = None,
-                 faults=None):
-        self.clock: Clock = clock or SystemClock()
+                 planner: bool = True, faults=None):
         self.faults = faults          # FaultPlan (chaos harness) or None
         self._plans: Dict[Tuple, Dict] = {}
         self._decisions: Dict[Tuple, Dict] = {}
@@ -145,19 +145,13 @@ class GangScheduler:
         self.layouts = {"stacked": 0, "concat": 0}   # gang launches by layout
         # flushes where an SLO class actually constrained the choice set
         self.slo_forced = {"latency": 0, "bulk": 0}
-        self.profile: Optional[Dict[str, float]] = None
+        self.tracer = Tracer()        # the farm's, when it profiles
 
     @property
     def dispatch_misses(self) -> int:
         """Distinct (group, bucketed rows) keys launched so far — each one
         is a fresh XLA compile; steady state stops growing this."""
         return len(self._dispatch_keys)
-
-    def _tick(self, stage: str, t0: float) -> float:
-        t1 = self.clock.now()
-        if self.profile is not None:
-            self.profile[stage] = self.profile.get(stage, 0.0) + (t1 - t0)
-        return t1
 
     def _plan(self, key: Tuple, members: List[Tuple[str, PRNGService]],
               mode: str) -> Dict:
@@ -369,92 +363,117 @@ class GangScheduler:
             # bookkeeping: a failed launch leaves every member's demand
             # parked at the same absolute rows, so a retry is bit-exact
             self.faults.on_launch([name for name, _, _, _ in members])
-        t0 = self.clock.now()
+        tr = self.tracer
         svc0 = members[0][1]
         cfg = svc0.config
-        plan = self._plan(key, [(name, svc) for name, svc, _, _ in members],
-                          layout)
         n_rows = max(demands)
         n_steps = 2 * n_rows
-        t0 = self._tick("plan", t0)
-        x0 = self._gather_x0(plan, members)
-        if layout == "stacked":
+        with tr.span("farm.plan", "plan"):
+            plan = self._plan(key, [(name, svc)
+                                    for name, svc, _, _ in members], layout)
+        with tr.span("farm.stack", "stack"):
+            x0 = self._gather_x0(plan, members)
             offs = plan["offs_buf"]
-            for ci, (_, _, _, offsets) in enumerate(members):
-                offs[ci, :] = offsets
-            row_map = np.asarray(demands, np.int32) if ragged else None
-            member_rows = list(demands) if ragged else [n_rows] * len(members)
-            t0 = self._tick("stack", t0)
-            words, state = ops.chaotic_bits_gang_stacked(
+            if layout == "stacked":
+                for ci, (_, _, _, offsets) in enumerate(members):
+                    offs[ci, :] = offsets
+                row_map = np.asarray(demands, np.int32) if ragged else None
+                member_rows = (list(demands) if ragged
+                               else [n_rows] * len(members))
+                # the sublane stack sweeps every row of every pool
+                s_blk = plan["s_block"]
+                computed = (n_rows * len(members)
+                            * (-(-plan["s_each"] // s_blk) * s_blk))
+                kernel = ops.chaotic_bits_gang_stacked
+            else:
+                for (start, live, _), (_, _, _, offsets) in zip(
+                        plan["spans"], members):
+                    offs[start:start + live] = offsets
+                if ragged:
+                    block_demand = np.repeat(
+                        np.asarray(demands, np.int64),
+                        [padded // plan["s_block"]
+                         for _, _, padded in plan["spans"]])
+                    eff = gang_effective_rows(block_demand, n_steps,
+                                              cfg.t_block, cfg.unroll)
+                    row_map = eff
+                    # every block of a member shares its demand -> same
+                    # eff rows
+                    member_rows, b0 = [], 0
+                    for _, _, padded in plan["spans"]:
+                        member_rows.append(int(eff[b0]))
+                        b0 += padded // plan["s_block"]
+                else:
+                    row_map = None
+                    member_rows = [n_rows] * len(members)
+                computed = sum(r * padded for r, (_, _, padded)
+                               in zip(member_rows, plan["spans"]))
+                kernel = functools.partial(ops.chaotic_bits_gang,
+                                           core_map=plan["core_map"])
+        with tr.span("farm.launch", "launch"):
+            words, state = kernel(
                 plan["params"], x0, n_steps, jnp.asarray(offs),
                 row_map=row_map, activation=svc0.activation,
                 backend=svc0.backend, mesh=svc0.mesh,
                 mesh_axis=svc0.mesh_axis, config=cfg)
-            words = np.asarray(words)
-            handed = [state[ci] for ci in range(len(members))]
-            member_out = [(words[:member_rows[ci], ci, :], handed[ci])
-                          for ci in range(len(members))]
-        else:
-            offs = plan["offs_buf"]
-            for (start, live, _), (_, _, _, offsets) in zip(
-                    plan["spans"], members):
-                offs[start:start + live] = offsets
-            if ragged:
-                block_demand = np.repeat(np.asarray(demands, np.int64),
-                                         [padded // plan["s_block"]
-                                          for _, _, padded in plan["spans"]])
-                eff = gang_effective_rows(block_demand, n_steps,
-                                          cfg.t_block, cfg.unroll)
-                row_map = eff
-                # every block of a member shares its demand -> same eff rows
-                member_rows, b0 = [], 0
-                for _, _, padded in plan["spans"]:
-                    member_rows.append(int(eff[b0]))
-                    b0 += padded // plan["s_block"]
+            words = tr.fetch(words)
+            if layout == "stacked":
+                handed = [state[ci] for ci in range(len(members))]
+                member_out = [(words[:member_rows[ci], ci, :], handed[ci])
+                              for ci in range(len(members))]
             else:
-                row_map = None
-                member_rows = [n_rows] * len(members)
-            t0 = self._tick("stack", t0)
-            words, state = ops.chaotic_bits_gang(
-                plan["params"], x0, n_steps,
-                jnp.asarray(offs), core_map=plan["core_map"],
-                row_map=row_map, activation=svc0.activation,
-                backend=svc0.backend, mesh=svc0.mesh,
-                mesh_axis=svc0.mesh_axis, config=cfg)
-            words = np.asarray(words)
-            handed = [state[start:start + live]
-                      for (start, live, _) in plan["spans"]]
-            member_out = [(words[:member_rows[ci], start:start + live],
-                           handed[ci])
-                          for ci, (start, live, _) in enumerate(plan["spans"])]
-        plan["last_x"], plan["handed"] = state, handed
-        self.launches += 1
-        self.layouts[layout] += 1
-        # ragged and padded launches of the same shape are distinct jit
-        # traces (row_map None vs array), hence distinct dispatch keys
-        self._dispatch_keys.add((plan["sig"], n_rows, bool(ragged)))
-        t0 = self._tick("launch", t0)
+                handed = [state[start:start + live]
+                          for (start, live, _) in plan["spans"]]
+                member_out = [(words[:member_rows[ci], start:start + live],
+                               handed[ci])
+                              for ci, (start, live, _)
+                              in enumerate(plan["spans"])]
+            plan["last_x"], plan["handed"] = state, handed
+            self.launches += 1
+            self.layouts[layout] += 1
+            # ragged and padded launches of the same shape are distinct
+            # jit traces (row_map None vs array), hence distinct dispatch
+            # keys
+            self._dispatch_keys.add((plan["sig"], n_rows, bool(ragged)))
+        used = self._used_lanes(member_rows,
+                                [svc for _, svc, _, _ in members])
         out: Dict[str, Dict[str, np.ndarray]] = {}
-        for (mwords, mstate), rows_c, (name, svc, _, _) in zip(
-                member_out, member_rows, members):
-            served = svc.absorb(mwords, mstate, rows_c, deliver=deliver)
-            if served:
-                out[name] = served
-        self._tick("absorb", t0)
+        with tr.span("farm.absorb", "absorb"):
+            for (mwords, mstate), rows_c, (name, svc, _, _) in zip(
+                    member_out, member_rows, members):
+                served = svc.absorb(mwords, mstate, rows_c, deliver=deliver)
+                if served:
+                    out[name] = served
+        tr.count(lanes_computed=computed, lanes_used=used)
         return out
 
     def _launch_solo(self, member: Tuple, n_rows: int, *,
                      deliver: bool) -> Dict[str, Dict[str, np.ndarray]]:
-        """A planner-split singleton: a plain per-core launch."""
+        """One core's own launch: a planner-split singleton, a core that
+        gangs with no other, or every core of a ``gang=False`` farm."""
         name, svc, _, offsets = member
         if self.faults is not None:
             self.faults.on_launch([name])
-        t0 = self.clock.now()
-        words, new_x = svc._launch(n_rows, jnp.asarray(offsets))
-        t0 = self._tick("launch", t0)
-        served = svc.absorb(words, new_x, n_rows, deliver=deliver)
-        self._tick("absorb", t0)
+        tr = self.tracer
+        with tr.span("farm.launch", "launch"):
+            words, new_x = svc._launch(n_rows, jnp.asarray(offsets))
+        s_blk = svc.config.s_block
+        computed = n_rows * (-(-svc.pool_x.shape[0] // s_blk) * s_blk)
+        used = self._used_lanes([n_rows], [svc])
+        with tr.span("farm.absorb", "absorb"):
+            served = svc.absorb(words, new_x, n_rows, deliver=deliver)
+        tr.count(lanes_computed=computed, lanes_used=used)
         return {name: served} if served else {}
+
+    def _used_lanes(self, rows: Sequence[int],
+                    svcs: Sequence[PRNGService]) -> int:
+        """Lane-rows of a launch whose words a tenant takes: each member's
+        rows times the lanes of its active tenants, read before its
+        ``absorb`` (which rolls the others back).  0 when not tracing."""
+        if not self.tracer.on:
+            return 0
+        return sum(r * svc.lanes_per_client * len(svc._active())
+                   for r, svc in zip(rows, svcs))
 
     def launch(self, key: Tuple,
                members: List[Tuple[str, PRNGService, int, np.ndarray]],
@@ -469,13 +488,12 @@ class GangScheduler:
         are bit-identical to the per-core path (chunk-invariance of the
         absolute-row Weyl indexing).
         """
-        t0 = self.clock.now()
         svc0 = members[0][1]
-        demands = tuple(_round_rows(n, svc0.config.t_block)
-                        for _, _, n, _ in members)
-        dec = self._decide(key, members, demands, slo)
-        self.decisions[dec["kind"]] += 1
-        self._tick("plan", t0)
+        with self.tracer.span("farm.plan", "plan"):
+            demands = tuple(_round_rows(n, svc0.config.t_block)
+                            for _, _, n, _ in members)
+            dec = self._decide(key, members, demands, slo)
+            self.decisions[dec["kind"]] += 1
         out: Dict[str, Dict[str, np.ndarray]] = {}
         for part in dec["parts"]:
             sub = [members[i] for i in part["members"]]
@@ -505,8 +523,10 @@ class OscillatorFarm:
     overhead.  ``auto_flush_rows`` is the coalescing threshold for
     ``request(..., auto_flush=True)``: the farm auto-flushes once total
     pending work reaches that many word rows (None = flush on every
-    auto-flush request).  ``profile=True`` accumulates per-stage flush
-    wall times (plan / stack / launch / absorb) in ``profile_stats``.
+    auto-flush request).  ``profile=True`` switches on the farm's
+    ``Tracer`` (``repro.serve.tracer``), shared with its services and its
+    ``AsyncOscillatorFarm``: stage timers and counters summed in
+    ``profile_stats``, and named spans in a ``jax.profiler`` trace.
     Every time read (the profile timers are the only ones) goes through
     the injectable ``clock`` (``repro.serve.clock``): the sync farm's own
     deferral/coalescing logic is flush-cycle- and row-counted, never
@@ -525,12 +545,9 @@ class OscillatorFarm:
         self.clock: Clock = clock or SystemClock()
         self.faults = faults          # FaultPlan (chaos harness) or None
         self._sched = GangScheduler(cost_model=gang_cost_model,
-                                    planner=planner, clock=self.clock,
-                                    faults=faults)
-        if profile:
-            self._sched.profile = {"plan": 0.0, "stack": 0.0,
-                                   "launch": 0.0, "absorb": 0.0,
-                                   "flushes": 0.0}
+                                    planner=planner, faults=faults)
+        self.tracer = Tracer(self.clock if profile else None)
+        self._sched.tracer = self.tracer
         self._deferred: set = set()   # cores deferred by the last flush
         # Self-healing state (see quarantine()/rotate()): quarantined
         # cores are skipped by every flush; standbys are cold spare
@@ -553,6 +570,7 @@ class OscillatorFarm:
                           burn_in=burn_in, activation=activation,
                           backend=backend, config=config, dtype=dtype,
                           mesh=mesh, mesh_axis=mesh_axis)
+        svc.tracer = self.tracer
         self.services[core] = svc
         if self.monitor is not None:
             self._install_hook(core)
@@ -564,6 +582,7 @@ class OscillatorFarm:
                        gang: bool = True, planner: bool = True,
                        gang_cost_model: Optional[GangCostModel] = None,
                        auto_flush_rows: Optional[int] = None,
+                       profile: bool = False,
                        **service_kw) -> "OscillatorFarm":
         """Build a farm from a ``generate_farm`` output directory.
 
@@ -587,7 +606,7 @@ class OscillatorFarm:
         farm_dir = pathlib.Path(farm_dir)
         farm = cls(gang=gang, planner=planner,
                    gang_cost_model=gang_cost_model,
-                   auto_flush_rows=auto_flush_rows)
+                   auto_flush_rows=auto_flush_rows, profile=profile)
         names = sorted(cores) if cores is not None else sorted(
             p.name for p in farm_dir.iterdir()
             if (p / "solution.json").exists() and (p / "weights.npz").exists())
@@ -650,6 +669,7 @@ class OscillatorFarm:
                           burn_in=burn_in, activation=activation,
                           backend=backend, config=config, dtype=dtype,
                           mesh=mesh, mesh_axis=mesh_axis)
+        svc.tracer = self.tracer
         self._standbys[core] = svc
         return svc
 
@@ -839,23 +859,12 @@ class OscillatorFarm:
                           for c in cores], deliver=deliver, slo=group_slo)
                 out.update(served)
             else:
-                prof = self._sched.profile
                 for c in cores:
                     svc = self.services[c]
-                    if self.faults is not None:
-                        self.faults.on_launch([c])
-                    t0 = self._sched.clock.now()
-                    n_rows = _round_rows(plans[c][0], svc.config.t_block)
-                    words, new_x = svc._launch(n_rows,
-                                               jnp.asarray(plans[c][1]))
-                    t1 = self._sched.clock.now()
-                    served = svc.absorb(words, new_x, n_rows,
-                                        deliver=deliver)
-                    if prof is not None:
-                        prof["launch"] += t1 - t0
-                        prof["absorb"] += self._sched.clock.now() - t1
-                    if served:
-                        out[c] = served
+                    out.update(self._sched._launch_solo(
+                        (c, svc, plans[c][0], plans[c][1]),
+                        _round_rows(plans[c][0], svc.config.t_block),
+                        deliver=deliver))
         # Launch-free delivery pass for cores with nothing to launch (their
         # buffers/outboxes may still owe words).  Deferred cores are fully
         # skipped: their buffers do not cover their pending requests yet.
@@ -868,8 +877,7 @@ class OscillatorFarm:
                 if served:
                     out[core] = served
         self._deferred = deferred_now
-        if self._sched.profile is not None:
-            self._sched.profile["flushes"] += 1.0
+        self.tracer.count(flushes=1)
         return out
 
     def draw(self, core: str, client: str, n_words: int) -> np.ndarray:
@@ -916,10 +924,10 @@ class OscillatorFarm:
 
     @property
     def profile_stats(self) -> Optional[Dict[str, float]]:
-        """Accumulated per-stage flush seconds (``profile=True`` farms):
-        plan / stack / launch / absorb, plus the flush count."""
-        return (dict(self._sched.profile)
-                if self._sched.profile is not None else None)
+        """The tracer's totals (``profile=True`` farms, else None): stage
+        seconds by ``repro.serve.tracer.TIMERS`` key, and the sums of
+        ``COUNTERS``, the flush count among them."""
+        return self.tracer.stats()
 
     # -- resumability -------------------------------------------------------
 

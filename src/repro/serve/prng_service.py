@@ -38,6 +38,7 @@ import numpy as np
 from repro.kernels import ops
 from repro.prng.stream import (_lineage_counter, _round_rows,
                                _splitmix_seeds, effective_burn_in)
+from repro.serve.tracer import Tracer
 
 
 @dataclasses.dataclass(eq=False)
@@ -88,6 +89,9 @@ class PRNGService:
         # The hook must be cheap and thread-safe — under an offloaded
         # front-end, absorb() runs on the launch executor thread.
         self.sample_hook = None
+        # Stage timers and counters: off unless a profiling farm hands
+        # over its own (``OscillatorFarm(profile=True)``).
+        self.tracer = Tracer()
         # Words already served by a flush but not yet returned to their
         # requester (a draw() for one client must not drop co-tenants'
         # flushed requests).
@@ -202,7 +206,7 @@ class PRNGService:
             words = np.asarray(words)
             if self.sample_hook is not None:
                 self.sample_hook(words)
-            active = [c for c in self._by_slot() if c.pending - len(c.buf) > 0]
+            active = self._active()
             for c in active:
                 mine = words[:, c.slot * L:(c.slot + 1) * L].reshape(-1)
                 c.buf = np.concatenate([c.buf, mine])
@@ -290,6 +294,11 @@ class PRNGService:
     def _by_slot(self) -> List[_Client]:
         return sorted(self.clients.values(), key=lambda c: c.slot)
 
+    def _active(self) -> List[_Client]:
+        """Clients that take the next launch's words: those whose pending
+        draws their buffers do not cover.  The others ride it, frozen."""
+        return [c for c in self._by_slot() if c.pending - len(c.buf) > 0]
+
     def _launch(self, n_rows: int, offsets: jax.Array):
         """The one batched pool launch: ((n_rows, S_pool) words, new state).
 
@@ -309,7 +318,7 @@ class PRNGService:
             run = shard_stream_pool(run, self.mesh, self.mesh_axis)
         words, new_x = run(self.pool_x, offsets)
         self.launches += 1
-        return np.asarray(words), new_x
+        return self.tracer.fetch(words), new_x
 
     # -- resumability -------------------------------------------------------
 
