@@ -744,7 +744,10 @@ class OscillatorFarm:
             faults.bind(core, svc)
 
         def hook(slab, _core=core, _svc=svc):
-            w = slab.reshape(-1)[:cap]
+            # only the rows that hold the first ``cap`` words: a gang
+            # member's strided slab is not copied whole for the sample
+            w = slab[:-(-cap // slab.shape[1])].reshape(-1)[:cap]
+            _svc.tracer.count(absorb_words_copied=w.size)
             if faults is not None:
                 w = faults.corrupt_sample(_core, _svc, w)
             monitor.ingest(_core, w)

@@ -207,10 +207,19 @@ class PRNGService:
             if self.sample_hook is not None:
                 self.sample_hook(words)
             active = self._active()
+            copied = 0
             for c in active:
-                mine = words[:, c.slot * L:(c.slot + 1) * L].reshape(-1)
-                c.buf = np.concatenate([c.buf, mine])
+                # each word copied once: the tenant's (n_rows, L) lanes,
+                # row-major, land after its leftover words (if any)
+                old = len(c.buf)
+                buf = np.empty(old + n_rows * L, words.dtype)
+                buf[:old] = c.buf
+                buf[old:].reshape(n_rows, L)[...] = \
+                    words[:, c.slot * L:(c.slot + 1) * L]
+                c.buf = buf
                 c.row += n_rows
+                copied += buf.size
+            self.tracer.count(absorb_words_copied=copied)
             active_slots = {c.slot for c in active}
             idle_lanes = np.concatenate(
                 [np.arange(c.slot * L, (c.slot + 1) * L)

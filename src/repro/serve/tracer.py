@@ -30,10 +30,11 @@ from repro.clock import Clock
 TIMERS = ("plan", "stack", "launch", "launch_wait", "launch_copy", "absorb",
           "commit", "resolve")
 #: Sums that are not span times: flushes, the committed draws' summed
-#: queue wait (seconds) and count, and the lane-rows the kernels computed
-#: and the lane-rows whose words a tenant buffered.
+#: queue wait (seconds) and count, the lane-rows the kernels computed
+#: and the lane-rows whose words a tenant buffered, and the words absorb
+#: wrote on the host (tenant buffers and the health monitor's sample).
 COUNTERS = ("flushes", "queue_wait_s", "draws_committed", "lanes_computed",
-            "lanes_used")
+            "lanes_used", "absorb_words_copied")
 
 _OFF = contextlib.nullcontext()
 

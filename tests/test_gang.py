@@ -17,6 +17,7 @@ import pytest
 from repro.core.dse import Candidate
 from repro.kernels import ops
 from repro.serve.farm import OscillatorFarm, _compat_key
+from repro.serve.health import HealthMonitor
 
 from test_kernels import _mk
 
@@ -329,3 +330,141 @@ def test_deadline_deferral_and_auto_flush():
     ref = solo.flush()
     for core in ref:
         np.testing.assert_array_equal(out[core]["t"], ref[core]["t"])
+
+
+# ---------------------------------------------------------------------------
+# Absorb: one host copy per delivered word
+# ---------------------------------------------------------------------------
+
+#: clients per core of each launch shape
+SHAPES = {"solo": [2], "stacked": [2, 2], "concat": [1, 2]}
+WHOLE, LEFTOVER = 4 * 128, 5 * 128 + 3   # 4 rows; 6 rows launched as 8
+
+
+class _Sampled(HealthMonitor):
+    """Keeps a copy of every sample the farm's hook hands it."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.got = {}
+
+    def ingest(self, core, words):
+        self.got.setdefault(core, []).append(np.array(words))
+
+
+def _absorb_farm(shape, **kw):
+    members = _compatible_members(len(SHAPES[shape]))
+    farm = _farm(True, members, **kw)
+    for (core, *_), n in zip(members, SHAPES[shape]):
+        for j in range(n):
+            farm.register(core, f"u{j}", seed=40 + j)
+    return farm
+
+
+def _record_launches(farm):
+    """Wrap each service's absorb: per core, every launch's slab, whether
+    it was contiguous, each active tenant's leftover word count, and the
+    words concatenated buffers took from it (``words[:, slot]`` row-major,
+    appended to the leftover)."""
+    seen = {core: [] for core in farm.cores}
+    for core, svc in farm.services.items():
+        def absorb(words, new_x, n_rows, *, deliver=True, _core=core,
+                   _svc=svc, _inner=svc.absorb):
+            if n_rows > 0:
+                L = _svc.lanes_per_client
+                slab = np.array(words)
+                active = _svc._active()
+                seen[_core].append({
+                    "slab": slab,
+                    "contiguous": np.asarray(words).flags.c_contiguous,
+                    "leftover": {c.name: len(c.buf) for c in active},
+                    "gathered": {
+                        c.name: slab[:, c.slot * L:(c.slot + 1) * L]
+                        .reshape(-1) for c in active}})
+            return _inner(words, new_x, n_rows, deliver=deliver)
+        svc.absorb = absorb
+    return seen
+
+
+def _serve_rounds(farm, n_words, rounds=3):
+    """Every tenant draws ``n_words`` a round, except that in the second
+    round only ``u0`` does (the others ride the launch idle)."""
+    got = {(core, name): [] for core in farm.cores
+           for name in farm.services[core].clients}
+    for r in range(rounds):
+        for core, name in got:
+            if r != 1 or name == "u0":
+                farm.request(core, name, n_words)
+        for core, by_client in farm.flush().items():
+            for name, w in by_client.items():
+                got[(core, name)].append(w)
+    return got
+
+
+@pytest.mark.parametrize("n_words", [WHOLE, LEFTOVER],
+                         ids=["empty-buffers", "leftover-buffers"])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_absorb_delivers_what_concatenated_buffers_delivered(shape, n_words):
+    """Delivered words and the leftover buffers are bit-identical to the
+    algorithm that concatenated each launch's gathered lanes onto the
+    buffer, for solo, stacked-gang and lane-concat launches."""
+    farm = _absorb_farm(shape)
+    seen = _record_launches(farm)
+    got = _serve_rounds(farm, n_words)
+    for layout, n in farm.layout_launches.items():
+        assert (n > 0) == (shape == layout), layout
+    for (core, name), draws in got.items():
+        want = np.concatenate([launch["gathered"][name]
+                               for launch in seen[core]
+                               if name in launch["gathered"]])
+        have = np.concatenate(draws)
+        assert have.size == n_words * (3 if name == "u0" else 2)
+        np.testing.assert_array_equal(have, want[:have.size])
+        np.testing.assert_array_equal(farm.services[core].clients[name].buf,
+                                      want[have.size:])
+        if n_words == WHOLE:
+            assert want.size == have.size
+        else:
+            assert any(launch["leftover"].get(name)
+                       for launch in seen[core])
+
+
+@pytest.mark.parametrize("cap", [256, 300])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_monitor_sample_is_the_slabs_first_words(shape, cap):
+    """The hook hands ``ingest`` exactly ``slab.reshape(-1)[:cap]``, in
+    row-major order, also for a gang member's strided slab."""
+    farm = _absorb_farm(shape)
+    monitor = _Sampled(window_words=cap)
+    farm.attach_monitor(monitor)
+    seen = _record_launches(farm)
+    _serve_rounds(farm, LEFTOVER)
+    for core, launches in seen.items():
+        assert launches and len(monitor.got[core]) == len(launches)
+        for launch, sample in zip(launches, monitor.got[core]):
+            assert launch["contiguous"] == (shape == "solo")
+            np.testing.assert_array_equal(
+                sample, launch["slab"].reshape(-1)[:cap])
+
+
+@pytest.mark.parametrize("n_words", [WHOLE, LEFTOVER],
+                         ids=["empty-buffers", "leftover-buffers"])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_absorb_counts_each_word_it_copies_once(shape, n_words):
+    """``absorb_words_copied``: the words buffered, the leftover words
+    copied beside them, and the monitor's sample; with empty buffers,
+    just the words buffered and the sample."""
+    cap = 256
+    farm = _absorb_farm(shape, profile=True)
+    farm.attach_monitor(_Sampled(window_words=cap))
+    seen = _record_launches(farm)
+    _serve_rounds(farm, n_words)
+    launches = [launch for ls in seen.values() for launch in ls]
+    buffered = sum(w.size for launch in launches
+                   for w in launch["gathered"].values())
+    leftover = sum(n for launch in launches
+                   for n in launch["leftover"].values())
+    sampled = sum(min(cap, launch["slab"].size) for launch in launches)
+    assert (leftover == 0) == (n_words == WHOLE)
+    assert farm.profile_stats["absorb_words_copied"] == (
+        buffered + leftover + sampled)

@@ -39,7 +39,8 @@ HARNESS = {"frontend.commit", "frontend.resolve", "farm.flush",
            "service.absorb"}
 NEW_METRICS = ("queue_wait_ms_mean.bulk", "commit_ms_per_mword.bulk",
                "resolve_ms_per_mword.bulk", "device_wait_ms_per_mword.bulk",
-               "copy_ms_per_mword.bulk", "useful_lane_share.bulk")
+               "copy_ms_per_mword.bulk", "useful_lane_share.bulk",
+               "absorb_copies_per_word.bulk")
 
 
 class TickClock:
@@ -232,14 +233,16 @@ def _reader(name):
 def test_the_new_readers_by_hand():
     st = {"queue_wait_s": 0.3, "draws_committed": 100.0, "commit": 0.02,
           "resolve": 0.5, "launch_wait": 0.1, "launch_copy": 0.7,
-          "lanes_used": 900.0, "lanes_computed": 1000.0}
+          "lanes_used": 900.0, "lanes_computed": 1000.0,
+          "absorb_words_copied": 4_020_480.0}
     obs = {"stages": st, "words": 4_000_000}
     want = {"queue_wait_ms_mean.bulk": 3.0,
             "commit_ms_per_mword.bulk": 5.0,
             "resolve_ms_per_mword.bulk": 125.0,
             "device_wait_ms_per_mword.bulk": 25.0,
             "copy_ms_per_mword.bulk": 175.0,
-            "useful_lane_share.bulk": 90.0}
+            "useful_lane_share.bulk": 90.0,
+            "absorb_copies_per_word.bulk": 1.00512}
     for name, value in want.items():
         assert _reader(name)(obs) == pytest.approx(value), name
 
@@ -285,3 +288,4 @@ def test_a_tiny_bulk_window_carries_every_new_key():
         v = _reader(name)(obs)
         assert v is not None and math.isfinite(v), name
     assert _reader("useful_lane_share.bulk")(obs) <= 100.0
+    assert st["absorb_words_copied"] > 0
