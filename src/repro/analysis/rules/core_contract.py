@@ -18,10 +18,9 @@ call directly with ``word_offset`` forwarded into it.
 The rule guards the serving layer's side of the same contract too:
 ``src/repro/serve/`` must not wrap its own ``shard_map``.  Device
 sharding is owned by the launch stack — ``ops.chaotic_bits_gang(...,
-mesh=)`` / the sharded gang kernels and
-``distributed.sharding.shard_stream_pool`` — which carry the proven
-bit-identity and scalar-prefetch-slicing contracts
-(tests/test_sharded_gang.py).  A serve-layer ``shard_map`` would bypass
+mesh=)`` / ``ops.chaotic_bits(..., mesh=)`` and the sharded kernels —
+which carry the proven bit-identity and scalar-prefetch-slicing
+contracts (tests/test_sharded_gang.py, tests/test_mesh_solo_launch.py).  A serve-layer ``shard_map`` would bypass
 the gang scheduler entirely: words from such a launch are outside every
 equivalence suite, the planner cannot cost it, and the compat key /
 plan caches would not know its topology.
@@ -97,9 +96,9 @@ class CoreContractRule(Rule):
 
     _SERVE_MSG = (
         "serve/ must not wrap its own shard_map: sharded launches route "
-        "through the gang path (ops.chaotic_bits_gang(..., mesh=) / "
-        "shard_stream_pool), whose bit-identity to the 1-device and solo "
-        "paths is proven — a direct shard_map bypasses the gang "
+        "through the launch stack (ops.chaotic_bits_gang(..., mesh=) / "
+        "ops.chaotic_bits(..., mesh=)), whose bit-identity to the 1-device "
+        "and solo paths is proven — a direct shard_map bypasses the gang "
         "scheduler, the cost model, and the topology-keyed plan caches")
 
     def _check_serve(self, ctx: FileContext) -> Iterable[Finding]:

@@ -275,23 +275,6 @@ def named(spec: MeshSpec, pspec_tree: PyTree) -> PyTree:
                         is_leaf=lambda x: isinstance(x, P))
 
 
-def shard_stream_pool(fn, mesh: Mesh, axis: str = "data"):
-    """shard_map a PRNG stream-pool launch over the stream (lane) axis.
-
-    ``fn(x, offsets) -> (words, state)`` with x (S, I), offsets (S,),
-    words (rows, S), state (S, I).  Oscillator streams are embarrassingly
-    parallel (each lane evolves independently), so partitioning S across
-    devices is exact — each device runs the fused kernel on its shard and
-    the words gather back on the lane axis.  The mesh axis size must
-    divide S.
-    """
-    return jax.shard_map(
-        fn, mesh=mesh,
-        in_specs=(P(axis, None), P(axis)),
-        out_specs=(P(None, axis), P(axis, None)),
-        check_vma=False)
-
-
 def strip_dp_axes(pspec_tree: PyTree, spec: MeshSpec) -> PyTree:
     """Remove dp (FSDP) axes from every PartitionSpec — TP-only layout.
 

@@ -1065,6 +1065,58 @@ def gang_partition_maps(core_map, row_map, *, n_dev: int, n_rows: int):
             np.concatenate([rmap, np.zeros(pad, np.int32)]), pad)
 
 
+def chaotic_ann_bits_sharded(w1, b1, w2, b2, x0, offsets, coupling=None,
+                             *, mesh, mesh_axis: str = "data",
+                             n_steps: int, s_block: int = 256,
+                             t_block: int = 128, unroll: int = 1,
+                             activation: str = "relu",
+                             compute_unit: str = "vpu", lattice=None,
+                             interpret: bool = False):
+    """Solo fused bits launch partitioned across ``mesh[mesh_axis]``.
+
+    The pool's stream axis and its (S,) per-lane word offsets shard on
+    the named axis; the weights (and an mxu lattice's coupling operand)
+    are replicated as traced arguments.  Each device runs
+    ``chaotic_ann_bits_pallas`` on its contiguous run of lanes, so the
+    words are bit-identical to the unsharded launch.  The shard_map'd
+    callable is cached per (mesh, static config) and jitted, as the gang
+    variants are.  The pool must divide the device count:
+    ``ops.chaotic_bits`` pads it with dead lanes.
+    """
+    args = [w1, b1, w2, b2, x0, offsets]
+    if coupling is not None:
+        args.append(jnp.asarray(coupling))
+    fn = _sharded_bits_fn(mesh, mesh_axis, n_steps, s_block, t_block, unroll,
+                          activation, compute_unit, lattice,
+                          coupling is not None, interpret)
+    return fn(*args)
+
+
+@functools.lru_cache(maxsize=128)
+def _sharded_bits_fn(mesh, mesh_axis, n_steps, s_block, t_block, unroll,
+                     activation, compute_unit, lattice, has_cpl, interpret):
+    """Jitted shard_map'd solo bits launch, cached per (mesh, static
+    kernel config) — see ``_sharded_gang_bits_fn``."""
+    from jax.sharding import PartitionSpec as P
+
+    kw = dict(n_steps=n_steps, s_block=s_block, t_block=t_block,
+              unroll=unroll, activation=activation,
+              compute_unit=compute_unit, lattice=lattice,
+              interpret=interpret)
+    in_specs = [P(), P(), P(), P(), P(mesh_axis, None), P(mesh_axis)]
+    if has_cpl:
+        in_specs.append(P())
+
+    def local(w1, b1, w2, b2, x_l, off_l, *cpl):
+        return chaotic_ann_bits_pallas(w1, b1, w2, b2, x_l, off_l,
+                                       cpl[0] if cpl else None, **kw)
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=tuple(in_specs),
+        out_specs=(P(None, mesh_axis), P(mesh_axis, None)),
+        check_vma=False))
+
+
 def chaotic_ann_gang_bits_sharded(w1, b1, w2, b2, x0, core_map,
                                   word_offset=0, row_map=None, coupling=None,
                                   *, mesh,
@@ -1215,3 +1267,11 @@ def _sharded_gang_stacked_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
         local, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=(P(None, None, mesh_axis), P(None, mesh_axis, None)),
         check_vma=False))
+
+
+def sharded_launch_builds() -> int:
+    """Sharded launch callables built so far in this process, solo and
+    gang: the misses of their builders' caches.  A launch that raises
+    this count built (and will compile) a new program."""
+    return sum(f.cache_info().misses for f in (
+        _sharded_bits_fn, _sharded_gang_bits_fn, _sharded_gang_stacked_fn))

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.kernels import ref
 from repro.kernels.chaotic_ann import (chaotic_ann_bits_pallas,
+                                       chaotic_ann_bits_sharded,
                                        chaotic_ann_gang_bits_pallas,
                                        chaotic_ann_gang_bits_sharded,
                                        chaotic_ann_gang_stacked_pallas,
@@ -125,11 +126,29 @@ def chaotic_trajectory(params: Dict[str, jax.Array], x0: jax.Array, n_steps: int
         lattice=lattice, interpret=interpret, **kw)
 
 
+def _padded_launch(launch, x0: jax.Array, word_offset,
+                   pad: int) -> Tuple[jax.Array, jax.Array]:
+    """``launch(x, offsets)`` of the pool ``x0`` grown by ``pad`` dead lanes
+    (zero state, zero offset) so that it divides a mesh's device count,
+    with the offsets broadcast per lane; the dead lanes are sliced off the
+    words and the state it returns."""
+    s_total = x0.shape[0]
+    off = jnp.broadcast_to(jnp.asarray(word_offset, jnp.uint32), (s_total,))
+    if pad:
+        x0 = jnp.concatenate([x0, jnp.zeros((pad, x0.shape[1]), x0.dtype)])
+        off = jnp.concatenate([off, jnp.zeros(pad, jnp.uint32)])
+    words, state = launch(x0, off)
+    if pad:
+        words, state = words[:, :s_total], state[:s_total]
+    return words, state
+
+
 def chaotic_bits(params: Dict[str, jax.Array], x0: jax.Array, n_steps: int,
                  word_offset=0, *, activation: str = "relu",
                  backend: str = "auto", s_block: int = 256,
                  t_block: int = 128, unroll: int = 1,
                  compute_unit: str = "vpu",
+                 mesh=None, mesh_axis: str = "data",
                  config=None) -> Tuple[jax.Array, jax.Array]:
     """Fused PRNG draw: (n_steps // 2, S) uint32 words + (S, I) final state.
 
@@ -138,6 +157,13 @@ def chaotic_bits(params: Dict[str, jax.Array], x0: jax.Array, n_steps: int,
     packs it with ``pack_words`` — both produce the same words for the same
     float trajectory, which is the co-simulation contract tested in
     tests/test_fused_bits.py.
+
+    ``mesh``/``mesh_axis`` (pallas backends only) shard the pool's stream
+    axis across the named device axis, as ``chaotic_bits_gang`` does: a
+    pool that does not divide the device count is padded with dead lanes
+    (zero state, zero offset) until it does, as ``gang_partition_maps``
+    pads the block axis with dead blocks, and the padding is sliced away.
+    The 'ref' oracle ignores the mesh.
     """
     w1, b1, w2, b2 = params["w1"], params["b1"], params["w2"], params["b2"]
     kw = dict(s_block=s_block, t_block=t_block, unroll=unroll,
@@ -155,6 +181,13 @@ def chaotic_bits(params: Dict[str, jax.Array], x0: jax.Array, n_steps: int,
                                        activation)
         return pack_words(traj, word_offset), traj[-1]
     interpret = resolve_backend(backend) == "pallas_interpret"
+    if mesh is not None and int(mesh.shape[mesh_axis]) > 1:
+        return _padded_launch(
+            lambda x, off: chaotic_ann_bits_sharded(
+                w1, b1, w2, b2, x, off, cpl, mesh=mesh, mesh_axis=mesh_axis,
+                n_steps=n_steps, activation=activation, lattice=lattice,
+                interpret=interpret, **kw),
+            x0, word_offset, (-x0.shape[0]) % int(mesh.shape[mesh_axis]))
     return chaotic_ann_bits_pallas(
         w1, b1, w2, b2, x0, word_offset, cpl, n_steps=n_steps,
         activation=activation, lattice=lattice, interpret=interpret, **kw)
@@ -248,23 +281,13 @@ def chaotic_bits_gang(params: Dict[str, jax.Array], x0: jax.Array,
         part = partitioner if partitioner is not None else gang_partition_maps
         cmap_p, rmap_p, pad = part(core_map, rmap, n_dev=n_dev,
                                    n_rows=n_steps // 2)
-        s_total = x0.shape[0]
-        xp, offp = x0, jnp.broadcast_to(
-            jnp.asarray(word_offset, jnp.uint32), (s_total,))
-        if pad:
-            s_blk = kw["s_block"]
-            xp = jnp.concatenate(
-                [x0, jnp.zeros((pad * s_blk, x0.shape[1]), x0.dtype)])
-            offp = jnp.concatenate(
-                [offp, jnp.zeros(pad * s_blk, jnp.uint32)])
-        words, state = chaotic_ann_gang_bits_sharded(
-            params["w1"], params["b1"], params["w2"], params["b2"], xp,
-            cmap_p, offp, rmap_p, cpl, mesh=mesh, mesh_axis=mesh_axis,
-            n_steps=n_steps, activation=activation, lattice=lattice,
-            interpret=interpret, **kw)
-        if pad:
-            words, state = words[:, :s_total], state[:s_total]
-        return words, state
+        return _padded_launch(
+            lambda x, off: chaotic_ann_gang_bits_sharded(
+                params["w1"], params["b1"], params["w2"], params["b2"], x,
+                cmap_p, off, rmap_p, cpl, mesh=mesh, mesh_axis=mesh_axis,
+                n_steps=n_steps, activation=activation, lattice=lattice,
+                interpret=interpret, **kw),
+            x0, word_offset, pad * kw["s_block"])
     return chaotic_ann_gang_bits_pallas(
         params["w1"], params["b1"], params["w2"], params["b2"], x0,
         core_map, word_offset, rmap, cpl, n_steps=n_steps,

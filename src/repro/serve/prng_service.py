@@ -6,8 +6,8 @@ kernel launch*: each client owns a contiguous block of lanes on the stream
 axis of the fused bits kernel, so a single ``ops.chaotic_bits`` launch
 advances every client at once (the batched-MAC-array idea, lifted to the
 serving layer).  Multi-device scale-out shards the stream pool across
-devices with ``distributed.sharding.shard_stream_pool`` — lanes are
-embarrassingly parallel, so the partition is exact.
+devices with ``ops.chaotic_bits(..., mesh=)`` — lanes are embarrassingly
+parallel, so the partition is exact.
 
 Determinism contract: a client's word stream depends only on (weights,
 seed, lanes_per_client, kernel config) — never on which other clients are
@@ -81,7 +81,10 @@ class PRNGService:
         self.mesh = mesh
         self.mesh_axis = mesh_axis
         self.clients: Dict[str, _Client] = {}
-        self.pool_x: Optional[jax.Array] = None       # (n_clients * L, I)
+        self._pool: Optional[jax.Array] = None        # (n_clients * L, I)
+        # seed counters of registered clients whose lanes are not in the
+        # pool yet, in slot order (``pool_x`` joins them)
+        self._joining: List[int] = []
         self.launches = 0                             # batched pool launches
         # Optional observation hook: called with each launch's raw word
         # slab inside absorb(), off the delivery path (the farm's
@@ -100,7 +103,8 @@ class PRNGService:
     # -- registration -------------------------------------------------------
 
     def register(self, name: str, seed: Optional[int] = None) -> None:
-        """Add a named stream: seed its lane block, burn it in, join pool.
+        """Add a named stream: its lane block joins the pool, seeded and
+        burned in, the next time the pool is read (``pool_x``).
 
         With no explicit seed, one is derived from the client name so that
         distinct clients never silently share a stream; pass the same
@@ -110,21 +114,41 @@ class PRNGService:
             raise ValueError(f"client {name!r} already registered")
         if seed is None:
             seed = zlib.crc32(name.encode())
+        slot = len(self.clients)
+        self.clients[name] = _Client(name=name, slot=slot, seed=seed)
+        self._joining.append(_lineage_counter(seed, ()))
+
+    @property
+    def pool_x(self) -> Optional[jax.Array]:
+        """The (n_clients * L, I) lane pool, every registered client's
+        block in slot order."""
+        if self._joining:
+            self._join()
+        return self._pool
+
+    @pool_x.setter
+    def pool_x(self, x: Optional[jax.Array]) -> None:
+        self._pool = x
+
+    def _join(self) -> None:
+        """Seed and burn in the lane blocks of the clients registered
+        since the pool was last read, and append them to it: one burn-in
+        launch and one concatenate however many joined.  Lanes evolve
+        independently and burn-in starts every lane at word row 0, so a
+        block's state is what a launch of that block alone gives: a
+        client's stream never depends on who registered with it."""
         L = self.lanes_per_client
-        counter = _lineage_counter(seed, ())
-        x = _splitmix_seeds(jnp.asarray(counter, jnp.uint32), L,
-                            self.dim).astype(self.dtype)
+        counters = jnp.asarray(self._joining, jnp.uint32)[:, None, None]
+        x = _splitmix_seeds(counters, L, self.dim).reshape(
+            -1, self.dim).astype(self.dtype)
+        self._joining = []
         if self.burn_in:
-            # Dedicated small launch so a client's stream is independent of
-            # when it registered (burn-in never advances other clients).
             _, x = ops.chaotic_bits(
                 self.params, x, self.burn_in, jnp.uint32(0),
                 activation=self.activation, backend=self.backend,
                 config=self.config)
-        slot = len(self.clients)
-        self.clients[name] = _Client(name=name, slot=slot, seed=seed)
-        self.pool_x = x if self.pool_x is None else jnp.concatenate(
-            [self.pool_x, x], axis=0)
+        self._pool = x if self._pool is None else jnp.concatenate(
+            [self._pool, x], axis=0)
 
     # -- request/flush ------------------------------------------------------
 
@@ -220,14 +244,14 @@ class PRNGService:
                 c.row += n_rows
                 copied += buf.size
             self.tracer.count(absorb_words_copied=copied)
-            active_slots = {c.slot for c in active}
-            idle_lanes = np.concatenate(
-                [np.arange(c.slot * L, (c.slot + 1) * L)
-                 for c in self._by_slot() if c.slot not in active_slots]
-            ) if len(active_slots) < len(self.clients) else None
-            if idle_lanes is not None:
-                new_pool_x = new_pool_x.at[idle_lanes].set(
-                    self.pool_x[idle_lanes])
+            if len(active) < len(self.clients):
+                # idle clients' lanes keep their pre-launch state: a lane
+                # mask (one program per pool shape, whatever the count
+                # of idle clients) selects them from the current pool
+                frozen = np.ones(len(self.clients), bool)
+                frozen[[c.slot for c in active]] = False
+                new_pool_x = jnp.where(np.repeat(frozen, L)[:, None],
+                                       self.pool_x, new_pool_x)
             self.pool_x = new_pool_x
         out: Dict[str, np.ndarray] = {}
         for name, parked in self._outbox.items():
@@ -314,19 +338,12 @@ class PRNGService:
         Does NOT assign ``pool_x`` — ``absorb()`` owns that, because idle
         lanes must be rolled back against the pre-launch pool.
         """
-        n_steps = 2 * n_rows
-
-        def run(x, off):
-            return ops.chaotic_bits(
-                self.params, x, n_steps, off, activation=self.activation,
-                backend=self.backend, config=self.config)
-
-        s_pool = self.pool_x.shape[0]
-        if self.mesh is not None and s_pool % self.mesh.shape[self.mesh_axis] == 0:
-            from repro.distributed.sharding import shard_stream_pool
-            run = shard_stream_pool(run, self.mesh, self.mesh_axis)
-        words, new_x = run(self.pool_x, offsets)
+        words, new_x = ops.chaotic_bits(
+            self.params, self.pool_x, 2 * n_rows, offsets,
+            activation=self.activation, backend=self.backend,
+            config=self.config, mesh=self.mesh, mesh_axis=self.mesh_axis)
         self.launches += 1
+        self.tracer.launched(self.mesh, self.mesh_axis, words)
         return self.tracer.fetch(words), new_x
 
     # -- resumability -------------------------------------------------------
@@ -423,6 +440,7 @@ class PRNGService:
                 f"snapshot was taken with effective burn_in {snap_burn}, "
                 f"this service runs {self.burn_in}; streams would resume "
                 f"at positions the engine cannot reproduce")
+        self._joining = []
         self.pool_x = (jnp.asarray(snap["pool_x"], self.dtype)
                        if snap["pool_x"] is not None else None)
         self.clients = {

@@ -25,16 +25,21 @@ import jax
 import numpy as np
 
 from repro.clock import Clock
+from repro.kernels.chaotic_ann import sharded_launch_builds
 
 #: Seconds a span was open, by totals key.
 TIMERS = ("plan", "stack", "launch", "launch_wait", "launch_copy", "absorb",
           "commit", "resolve")
 #: Sums that are not span times: flushes, the committed draws' summed
 #: queue wait (seconds) and count, the lane-rows the kernels computed
-#: and the lane-rows whose words a tenant buffered, and the words absorb
-#: wrote on the host (tenant buffers and the health monitor's sample).
+#: and the lane-rows whose words a tenant buffered, the words absorb
+#: wrote on the host (tenant buffers and the health monitor's sample),
+#: the launches of pools on a mesh of more than one device and those of
+#: them whose words came back from every device of the mesh, and the
+#: sharded launch callables built (``Tracer.launched``).
 COUNTERS = ("flushes", "queue_wait_s", "draws_committed", "lanes_computed",
-            "lanes_used", "absorb_words_copied")
+            "lanes_used", "absorb_words_copied", "mesh_launches",
+            "mesh_launches_split", "launch_builds")
 
 _OFF = contextlib.nullcontext()
 
@@ -48,6 +53,7 @@ class Tracer:
             None if clock is None else dict.fromkeys(TIMERS + COUNTERS, 0.0))
         # the launch phase runs on the front-end's worker thread
         self._lock = threading.Lock()
+        self._builds = sharded_launch_builds() if clock is not None else 0
 
     @property
     def on(self) -> bool:
@@ -86,6 +92,24 @@ class Tracer:
             return None
         with self._lock:
             return dict(self._totals)
+
+    def launched(self, mesh, mesh_axis: str, words: jax.Array) -> None:
+        """Count one launch, before its words are fetched: the sharded
+        launch callables built since the tracer's previous launch (the
+        misses of the builders' caches, ``sharded_launch_builds``), and a
+        pool on a mesh of more than one device in ``mesh_launches``, and
+        in ``mesh_launches_split`` when its words lie on every device of
+        the mesh (the launch ran split over all of them)."""
+        if self._totals is None:
+            return
+        builds = sharded_launch_builds()
+        with self._lock:
+            built, self._builds = builds - self._builds, builds
+        sums = {"launch_builds": built}
+        if mesh is not None and int(mesh.shape[mesh_axis]) > 1:
+            split = set(words.sharding.device_set) >= set(mesh.devices.flat)
+            sums.update(mesh_launches=1, mesh_launches_split=int(split))
+        self.count(**sums)
 
     def fetch(self, words: jax.Array) -> np.ndarray:
         """A launch's words on the host.  Traced, as two spans: the wait

@@ -2,7 +2,7 @@
 """BAD: a serve-layer module wrapping its own shard_map around a launch.
 
 Sharding belongs to the launch stack (``ops.chaotic_bits_gang(...,
-mesh=)`` / ``shard_stream_pool``): a direct ``shard_map`` here bypasses
+mesh=)`` / ``ops.chaotic_bits(..., mesh=)``): a direct ``shard_map`` here bypasses
 the gang scheduler, the cost model, and the topology-keyed plan caches,
 and its words sit outside every bit-identity suite.
 """

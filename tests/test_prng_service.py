@@ -159,6 +159,37 @@ def test_idle_clients_frozen(params):
                                   solo.draw("idle", 500))
 
 
+def test_idle_rollback_compiles_nothing_per_idle_count(params):
+    """Freezing idle clients is one program per pool shape: once a flush
+    with idle clients has run, flushes with other counts of idle clients
+    compile nothing, and each idle client is still frozen."""
+    svc = _service(params)
+    for i in range(6):
+        svc.register(f"c{i}", seed=40 + i)
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+
+    def flush_first(k):
+        for i in range(k):
+            svc.request(f"c{i}", 512)
+        svc.flush()
+
+    flush_first(5)
+    flush_first(6)
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for k in (4, 1, 3, 2):
+            flush_first(k)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert [svc.clients[f"c{i}"].row for i in range(6)] == [24, 20, 16, 12,
+                                                            8, 4]
+
+
 def test_draw_never_drops_cotenant_requests(params):
     """A draw()-triggered flush parks other clients' served words in the
     outbox instead of discarding them; a later flush delivers them."""

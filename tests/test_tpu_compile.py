@@ -195,3 +195,45 @@ def test_sharded_lane_concat_gang_compiles_on_four_chips(topo):
                         maps_sharding=lanes)
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def test_sharded_solo_bits_compiles_on_four_chips(topo):
+    """The 4-16-4 core launched alone inside ``shard_map`` over four
+    described chips, at ``farm5.bulk_mesh4``'s pool (256 tenants, a
+    quarter of the lanes on each chip); the weights are replicated."""
+    c = _served("hyperlorenz")
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
+    rep = NamedSharding(mesh, P())
+    i, h, dt = c.i_dim, c.h_dim, jnp.bfloat16
+    fn = ca._sharded_bits_fn(mesh, "data", N_STEPS, c.s_block, c.t_block,
+                             c.unroll, "relu", c.compute_unit, None, False,
+                             False)
+    text = fn.lower(
+        _sds(rep, (i, h), dt), _sds(rep, (h,), dt), _sds(rep, (h, i), dt),
+        _sds(rep, (i,), dt),
+        _sds(NamedSharding(mesh, P("data", None)), (4 * S_CORE, i), dt),
+        _sds(NamedSharding(mesh, P("data")), (4 * S_CORE,), jnp.uint32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_sharded_stacked_gang_compiles_on_four_chips(topo):
+    """The four stacked 3-8 bf16 cores inside ``shard_map`` over four
+    described chips, at ``farm5.bulk_mesh4``'s pools: every chip keeps
+    the whole stack with a quarter of each pool's lanes."""
+    c = _served("chen")
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
+    rep = NamedSharding(mesh, P())
+    n, i, h, dt = 4, c.i_dim, c.h_dim, jnp.bfloat16
+    fn = ca._sharded_gang_stacked_fn(
+        mesh, "data", False, N_STEPS, c.s_block, c.t_block, c.unroll,
+        "relu", c.compute_unit, None, False)
+    text = fn.lower(
+        _sds(rep, (n, i, h), dt), _sds(rep, (n, h), dt),
+        _sds(rep, (n, h, i), dt), _sds(rep, (n, i), dt),
+        _sds(NamedSharding(mesh, P(None, "data", None)), (n, 4 * S_CORE, i),
+             dt),
+        _sds(NamedSharding(mesh, P(None, "data")), (n, 4 * S_CORE),
+             jnp.uint32),
+    ).compile().as_text()
+    assert "tpu_custom_call" in text
