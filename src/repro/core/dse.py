@@ -342,12 +342,14 @@ class GangCostModel:
     # Per-row cost of the ragged-stacked freeze (one mask compare + select
     # over the stacked state per word row); analytic default ~2 vreg ops.
     freeze_row_cycles: float = 4.0
-    # Extra dispatch cost per device beyond the first when a launch is
-    # shard_map'd across a mesh (collective setup, per-device program
-    # dispatch).  The compute/cell terms are counted on the *busiest
-    # device's shard* (``n_dev`` in launch_cycles/gang_cost/solo_cost), so
-    # this is the only term that grows with the mesh — ``fit(mesh=...)``
-    # measures it from a real sharded launch.
+    # Extra cost per device beyond the first when a launch is shard_map'd
+    # across a mesh (collective setup, per-device program dispatch, and
+    # the all-gather that brings every device's words onto each device
+    # inside the launch).  The compute/cell terms are counted on the
+    # *busiest device's shard* (``n_dev`` in launch_cycles/gang_cost/
+    # solo_cost), so this is the only term that grows with the mesh —
+    # ``fit(mesh=...)`` measures it from a real sharded launch, gather
+    # included.
     cross_dev_overhead_cycles: float = 10_000.0
     sec_per_cycle: Optional[float] = None
 

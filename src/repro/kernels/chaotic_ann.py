@@ -1065,6 +1065,15 @@ def gang_partition_maps(core_map, row_map, *, n_dev: int, n_rows: int):
             np.concatenate([rmap, np.zeros(pad, np.int32)]), pad)
 
 
+def _gather_lanes(words: jax.Array, mesh_axis: str) -> jax.Array:
+    """One device's words of a sharded launch, all-gathered over
+    ``mesh_axis`` on their lane (last) axis inside the launch's program:
+    every device returns the whole word slab, so the host copies one
+    device buffer instead of assembling one slice per device."""
+    return jax.lax.all_gather(words, mesh_axis, axis=words.ndim - 1,
+                              tiled=True)
+
+
 def chaotic_ann_bits_sharded(w1, b1, w2, b2, x0, offsets, coupling=None,
                              *, mesh, mesh_axis: str = "data",
                              n_steps: int, s_block: int = 256,
@@ -1078,10 +1087,13 @@ def chaotic_ann_bits_sharded(w1, b1, w2, b2, x0, offsets, coupling=None,
     the named axis; the weights (and an mxu lattice's coupling operand)
     are replicated as traced arguments.  Each device runs
     ``chaotic_ann_bits_pallas`` on its contiguous run of lanes, so the
-    words are bit-identical to the unsharded launch.  The shard_map'd
-    callable is cached per (mesh, static config) and jitted, as the gang
-    variants are.  The pool must divide the device count:
-    ``ops.chaotic_bits`` pads it with dead lanes.
+    words are bit-identical to the unsharded launch.  The words come
+    back whole on every device (gathered over the mesh inside the same
+    program); the final state stays sharded on its stream axis, where
+    the next launch reads it.  The shard_map'd callable is cached per
+    (mesh, static config) and jitted, as the gang variants are.  The
+    pool must divide the device count: ``ops.chaotic_bits`` pads it with
+    dead lanes.
     """
     args = [w1, b1, w2, b2, x0, offsets]
     if coupling is not None:
@@ -1108,13 +1120,13 @@ def _sharded_bits_fn(mesh, mesh_axis, n_steps, s_block, t_block, unroll,
         in_specs.append(P())
 
     def local(w1, b1, w2, b2, x_l, off_l, *cpl):
-        return chaotic_ann_bits_pallas(w1, b1, w2, b2, x_l, off_l,
-                                       cpl[0] if cpl else None, **kw)
+        words, state = chaotic_ann_bits_pallas(
+            w1, b1, w2, b2, x_l, off_l, cpl[0] if cpl else None, **kw)
+        return _gather_lanes(words, mesh_axis), state
 
     return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=(P(None, mesh_axis), P(mesh_axis, None)),
-        check_vma=False))
+        out_specs=(P(), P(mesh_axis, None)), check_vma=False))
 
 
 def chaotic_ann_gang_bits_sharded(w1, b1, w2, b2, x0, core_map,
@@ -1136,9 +1148,11 @@ def chaotic_ann_gang_bits_sharded(w1, b1, w2, b2, x0, core_map,
     evolve independently and word whitening is indexed by absolute
     per-lane row offsets, so the result is bit-identical to the
     unsharded gang launch (and hence to per-core launches) at any device
-    count.  The shard_map'd callable is cached per (mesh, static config)
-    and jitted, so steady-state flushes reuse one compiled program per
-    launch shape.
+    count.  The words come back whole on every device (gathered over the
+    mesh inside the same program); the final state stays sharded on its
+    stream axis.  The shard_map'd callable is cached per (mesh, static
+    config) and jitted, so steady-state flushes reuse one compiled
+    program per launch shape.
 
     The block axis must divide the device count — pad the maps (and the
     pool) with ``gang_partition_maps`` dead blocks first.
@@ -1175,7 +1189,9 @@ def _sharded_gang_bits_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
     """Jitted shard_map'd lane-concat gang launch, cached per (mesh,
     static kernel config).  Weights/pool/maps are traced arguments, so
     jit retraces only when a launch SHAPE is new — per-flush weight or
-    demand values hit the compiled program."""
+    demand values hit the compiled program.  Each device's words are
+    all-gathered into one replicated slab (``_gather_lanes``); the state
+    keeps its lane sharding."""
     from jax.sharding import PartitionSpec as P
 
     kw = dict(n_steps=n_steps, s_block=s_block, t_block=t_block,
@@ -1193,13 +1209,13 @@ def _sharded_gang_bits_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
         rest = list(rest)
         rmap_l = rest.pop(0) if has_rmap else None
         cpl = rest.pop(0) if has_cpl else None
-        return chaotic_ann_gang_bits_pallas(
+        words, state = chaotic_ann_gang_bits_pallas(
             w1, b1, w2, b2, x_l, cmap_l, off_l, rmap_l, cpl, **kw)
+        return _gather_lanes(words, mesh_axis), state
 
     return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=(P(None, mesh_axis), P(mesh_axis, None)),
-        check_vma=False))
+        out_specs=(P(), P(mesh_axis, None)), check_vma=False))
 
 
 def chaotic_ann_gang_stacked_sharded(w1, b1, w2, b2, x0, word_offset=0,
@@ -1215,7 +1231,10 @@ def chaotic_ann_gang_stacked_sharded(w1, b1, w2, b2, x0, word_offset=0,
     The group's equal-size pools shard on the STREAM axis (every device
     keeps all C cores stacked on sublanes, with 1/n_dev of each pool's
     lanes); the (C,) row map is replicated since a core's freeze row is
-    lane-independent.  Weight tables are replicated as traced arguments
+    lane-independent.  The words come back whole on every device
+    (gathered over the mesh inside the same program); the final state
+    stays sharded on its stream axis.  Weight tables are replicated as
+    traced arguments
     (``P()`` specs), and the shard_map'd callable is cached per (mesh,
     static config) + jitted — same no-recompile-per-flush discipline as
     the lane-concat variant.  The pool size must divide the device
@@ -1256,17 +1275,14 @@ def _sharded_gang_stacked_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
     if has_rmap:
         in_specs.append(P())            # (C,) freeze rows: lane-independent
 
-        def local(w1, b1, w2, b2, x_l, off_l, rmap_l):
-            return chaotic_ann_gang_stacked_pallas(
-                w1, b1, w2, b2, x_l, off_l, rmap_l, **kw)
-    else:
-        def local(w1, b1, w2, b2, x_l, off_l):
-            return chaotic_ann_gang_stacked_pallas(
-                w1, b1, w2, b2, x_l, off_l, None, **kw)
+    def local(w1, b1, w2, b2, x_l, off_l, *rmap):
+        words, state = chaotic_ann_gang_stacked_pallas(
+            w1, b1, w2, b2, x_l, off_l, rmap[0] if rmap else None, **kw)
+        return _gather_lanes(words, mesh_axis), state
+
     return jax.jit(jax.shard_map(
         local, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=(P(None, None, mesh_axis), P(None, mesh_axis, None)),
-        check_vma=False))
+        out_specs=(P(), P(None, mesh_axis, None)), check_vma=False))
 
 
 def sharded_launch_builds() -> int:

@@ -163,7 +163,10 @@ def chaotic_bits(params: Dict[str, jax.Array], x0: jax.Array, n_steps: int,
     pool that does not divide the device count is padded with dead lanes
     (zero state, zero offset) until it does, as ``gang_partition_maps``
     pads the block axis with dead blocks, and the padding is sliced away.
-    The 'ref' oracle ignores the mesh.
+    The words come back whole on every device of the mesh (gathered
+    inside the launch's program, so the host copies one buffer); the
+    state stays sharded on its stream axis.  The 'ref' oracle ignores
+    the mesh.
     """
     w1, b1, w2, b2 = params["w1"], params["b1"], params["w2"], params["b2"]
     kw = dict(s_block=s_block, t_block=t_block, unroll=unroll,
@@ -227,7 +230,8 @@ def chaotic_bits_gang(params: Dict[str, jax.Array], x0: jax.Array,
     ``mesh``/``mesh_axis`` (pallas backends only) shard the launch across
     the named device axis: the pool and both scalar-prefetch maps
     partition on the lane/block axis while the weight slabs replicate, so
-    one *logical* gang launch spans every device bit-identically.
+    one *logical* gang launch spans every device bit-identically.  The
+    words come back whole on every device; the state stays sharded.
     ``partitioner`` overrides the per-device map partitioner (default
     ``gang_partition_maps``, which pads the block axis with dead zero-row
     blocks until it divides the device count).  The 'ref' oracle ignores
@@ -323,8 +327,9 @@ def chaotic_bits_gang_stacked(params: Dict[str, jax.Array], x0: jax.Array,
     pools on the STREAM axis across the named device axis — every device
     keeps the full sublane stack with 1/n_dev of each pool's lanes; the
     pool size must divide the device count (the gang scheduler checks
-    this before choosing the stacked layout on a mesh).  The 'ref' oracle
-    ignores the mesh.
+    this before choosing the stacked layout on a mesh).  The words come
+    back whole on every device; the state stays sharded.  The 'ref'
+    oracle ignores the mesh.
     """
     kw = dict(s_block=s_block, t_block=t_block, unroll=unroll,
               compute_unit=compute_unit)

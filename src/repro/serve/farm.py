@@ -416,7 +416,7 @@ class GangScheduler:
                 row_map=row_map, activation=svc0.activation,
                 backend=svc0.backend, mesh=svc0.mesh,
                 mesh_axis=svc0.mesh_axis, config=cfg)
-            tr.launched(svc0.mesh, svc0.mesh_axis, words)
+            tr.launched(svc0.mesh, svc0.mesh_axis, state)
             words = tr.fetch(words)
             if layout == "stacked":
                 handed = [state[ci] for ci in range(len(members))]

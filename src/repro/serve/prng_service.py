@@ -343,7 +343,7 @@ class PRNGService:
             activation=self.activation, backend=self.backend,
             config=self.config, mesh=self.mesh, mesh_axis=self.mesh_axis)
         self.launches += 1
-        self.tracer.launched(self.mesh, self.mesh_axis, words)
+        self.tracer.launched(self.mesh, self.mesh_axis, new_x)
         return self.tracer.fetch(words), new_x
 
     # -- resumability -------------------------------------------------------

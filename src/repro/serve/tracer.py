@@ -35,11 +35,12 @@ TIMERS = ("plan", "stack", "launch", "launch_wait", "launch_copy", "absorb",
 #: and the lane-rows whose words a tenant buffered, the words absorb
 #: wrote on the host (tenant buffers and the health monitor's sample),
 #: the launches of pools on a mesh of more than one device and those of
-#: them whose words came back from every device of the mesh, and the
-#: sharded launch callables built (``Tracer.launched``).
+#: them that ran split over every device of the mesh, the sharded launch
+#: callables built (``Tracer.launched``), and the fetches whose words the
+#: host assembled from more than one device buffer (``Tracer.fetch``).
 COUNTERS = ("flushes", "queue_wait_s", "draws_committed", "lanes_computed",
             "lanes_used", "absorb_words_copied", "mesh_launches",
-            "mesh_launches_split", "launch_builds")
+            "mesh_launches_split", "launch_builds", "fetch_assembled")
 
 _OFF = contextlib.nullcontext()
 
@@ -93,13 +94,15 @@ class Tracer:
         with self._lock:
             return dict(self._totals)
 
-    def launched(self, mesh, mesh_axis: str, words: jax.Array) -> None:
+    def launched(self, mesh, mesh_axis: str, state: jax.Array) -> None:
         """Count one launch, before its words are fetched: the sharded
         launch callables built since the tracer's previous launch (the
         misses of the builders' caches, ``sharded_launch_builds``), and a
         pool on a mesh of more than one device in ``mesh_launches``, and
-        in ``mesh_launches_split`` when its words lie on every device of
-        the mesh (the launch ran split over all of them)."""
+        in ``mesh_launches_split`` when the launch's final state lies on
+        every device of the mesh (the launch ran split over all of them).
+        The state decides, not the words: a sharded launch gathers its
+        words onto every device, so where they lie proves nothing."""
         if self._totals is None:
             return
         builds = sharded_launch_builds()
@@ -107,16 +110,21 @@ class Tracer:
             built, self._builds = builds - self._builds, builds
         sums = {"launch_builds": built}
         if mesh is not None and int(mesh.shape[mesh_axis]) > 1:
-            split = set(words.sharding.device_set) >= set(mesh.devices.flat)
+            split = set(state.sharding.device_set) >= set(mesh.devices.flat)
             sums.update(mesh_launches=1, mesh_launches_split=int(split))
         self.count(**sums)
 
     def fetch(self, words: jax.Array) -> np.ndarray:
         """A launch's words on the host.  Traced, as two spans: the wait
         for the device to finish the launch, then the device-to-host
-        copy."""
+        copy; and counted in ``fetch_assembled`` when the words lie in
+        more than one device buffer, which the copy assembles on the
+        host."""
         if self._totals is None:
             return np.asarray(words)
+        self.count(fetch_assembled=int(
+            not words.sharding.is_fully_replicated
+            and len(words.addressable_shards) > 1))
         with self.span("farm.launch.wait", "launch_wait"):
             jax.block_until_ready(words)
         with self.span("farm.launch.copy", "launch_copy"):

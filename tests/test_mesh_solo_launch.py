@@ -8,8 +8,15 @@ launch runs one jitted ``shard_map`` per step count, built once and
 reused, as the gang launches are.  A pool that does not divide the device
 count is padded with dead lanes, as the gang path pads its block axis.
 The tracer counts every launch of a pool on a mesh (``mesh_launches``),
-those whose words came back from every device (``mesh_launches_split``)
-and the sharded callables built (``launch_builds``).
+those whose state lies on every device (``mesh_launches_split``), the
+sharded callables built (``launch_builds``) and the fetches whose words
+the host had to assemble from several device buffers
+(``fetch_assembled``).
+
+Every sharded launch, solo, lane-concat gang or stacked gang, gathers its
+words over the mesh inside its program: they come back whole on every
+device, bit-identical to the unsharded launch, while the state keeps its
+lane sharding for the next launch.
 
 Tier-1 runs on one CPU device, so the cases run in one subprocess on
 four forced host devices and the tests read its findings.
@@ -39,9 +46,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from test_gang import _params as gang_params, _stacked
 from test_kernels import _mk
 from bench.cores import ann
 from repro.kernels import ops
+from repro.kernels.chaotic_ann import sharded_launch_builds
 from repro.serve.farm import OscillatorFarm
 
 compiles = []
@@ -129,6 +138,7 @@ out["builds_all"] = st["launch_builds"]
 out["steady_compiles"] = compiles[steady_from:]
 out["mesh_launches"] = st["mesh_launches"]
 out["mesh_launches_split"] = st["mesh_launches_split"]
+out["fetch_assembled"] = st["fetch_assembled"]
 
 # a pool of 3 x 6 = 18 lanes does not divide 4 devices: dead lanes pad it
 ragged, ragged_plain = farm(MESH, lanes=6, tenants=3), farm(None, lanes=6,
@@ -145,7 +155,8 @@ st = ragged.profile_stats
 out["ragged"] = {"equal": ok, "pool": int(ragged.services["wide"]
                                           .pool_x.shape[0]),
                  "mesh_launches": st["mesh_launches"],
-                 "mesh_launches_split": st["mesh_launches_split"]}
+                 "mesh_launches_split": st["mesh_launches_split"],
+                 "fetch_assembled": st["fetch_assembled"]}
 
 # the reference backend ignores the mesh: its launches are not split
 oracle = farm(MESH, tenants=1, backend="ref")
@@ -153,6 +164,60 @@ flush(oracle, 128)
 st = oracle.profile_stats
 out["ref_backend"] = {"mesh_launches": st["mesh_launches"],
                       "mesh_launches_split": st["mesh_launches_split"]}
+
+# each sharded builder on its own, from a pool that divides the mesh and,
+# where the layout allows one, from a pool that does not
+KW = dict(backend="pallas_interpret", s_block=128, t_block=32, unroll=2)
+GANG = _stacked([gang_params(key=k) for k in range(3)])
+rng = np.random.default_rng(5)
+
+
+def pool(shape, key):
+    x = _mk(3, 8, int(np.prod(shape[:-1])), key=key)[4]
+    offs = rng.integers(0, 10_000, size=shape[:-1]).astype(np.uint32)
+    return x.reshape(shape), jnp.asarray(offs)
+
+
+def solo(lanes):
+    x, offs = pool((lanes, 3), 21)
+    return (lambda mesh: ops.chaotic_bits(PARAMS["small"], x, 16, offs,
+                                          mesh=mesh, **KW)), 0
+
+
+def gang(blocks):
+    x, offs = pool((blocks * 128, 3), 22)
+    cmap = np.arange(blocks, dtype=np.int32) % 3
+    return (lambda mesh: ops.chaotic_bits_gang(
+        GANG, x, 16, offs, core_map=cmap, mesh=mesh, **KW)), 0
+
+
+def stacked(lanes):
+    x, offs = pool((3, lanes, 3), 23)
+    return (lambda mesh: ops.chaotic_bits_gang_stacked(
+        GANG, x, 16, offs, mesh=mesh, **KW)), 1
+
+
+CASES = {"solo": solo(512), "gang": gang(4), "stacked": stacked(512),
+         "solo_padded": solo(18), "gang_padded": gang(6)}
+out["kernels"] = {}
+for case, (launch, lane_axis) in CASES.items():
+    want_w, want_x = launch(None)
+    got_w, got_x = launch(MESH)
+    builds = sharded_launch_builds()
+    again_w, again_x = launch(MESH)
+    spec = tuple(got_x.sharding.spec) + (None,) * got_x.ndim
+    out["kernels"][case] = {
+        "replicated": bool(got_w.sharding.is_fully_replicated),
+        "on_every_device": got_w.sharding.device_set == set(MESH.devices.flat),
+        "words_equal": bool(np.array_equal(got_w, want_w)),
+        "shapes": [list(got_w.shape), list(want_w.shape),
+                   list(got_x.shape), list(want_x.shape)],
+        "state_equal": same(got_x, want_x),
+        "state_lane_sharded": (not got_x.sharding.is_fully_replicated
+                               and spec[lane_axis] == "data"),
+        "builds_again": sharded_launch_builds() - builds,
+        "again_equal": bool(np.array_equal(again_w, got_w))
+                       and same(again_x, got_x)}
 print("RESULT " + json.dumps(out))
 """
 
@@ -203,3 +268,46 @@ def test_a_pool_that_does_not_divide_the_mesh_is_padded_and_counted(found):
 def test_a_launch_that_ignores_the_mesh_counts_as_unsplit(found):
     assert found["ref_backend"] == {"mesh_launches": 2.0,
                                     "mesh_launches_split": 0.0}
+
+
+def test_no_fetch_on_the_mesh_assembles_its_words(found):
+    assert found["fetch_assembled"] == 0
+    assert found["ragged"]["fetch_assembled"] == 0
+
+
+KERNEL_CASES = ("solo", "gang", "stacked", "solo_padded", "gang_padded")
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_sharded_words_come_back_whole_on_every_device(found, case):
+    got = found["kernels"][case]
+    assert got["replicated"]
+    assert got["on_every_device"]
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_gathered_words_and_state_match_the_unsharded_launch(found, case):
+    got = found["kernels"][case]
+    assert got["words_equal"]
+    assert got["state_equal"]
+
+
+@pytest.mark.parametrize("case", ("solo", "gang", "stacked"))
+def test_the_state_keeps_its_lane_sharding(found, case):
+    assert found["kernels"][case]["state_lane_sharded"]
+
+
+@pytest.mark.parametrize("case", KERNEL_CASES)
+def test_a_second_launch_of_a_shape_builds_nothing(found, case):
+    got = found["kernels"][case]
+    assert got["builds_again"] == 0
+    assert got["again_equal"]
+
+
+@pytest.mark.parametrize("case", ("solo_padded", "gang_padded"))
+def test_a_padded_pool_slices_its_dead_lanes_off_the_gathered_words(
+        found, case):
+    words, want_words, state, want_state = found["kernels"][case]["shapes"]
+    lanes = {"solo_padded": 18, "gang_padded": 6 * 128}[case]
+    assert words == want_words == [8, lanes]
+    assert state == want_state == [lanes, 3]

@@ -195,6 +195,7 @@ def test_sharded_lane_concat_gang_compiles_on_four_chips(topo):
                         maps_sharding=lanes)
     text = fn.lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "all-gather" in text      # the words, gathered on chip
 
 
 def test_sharded_solo_bits_compiles_on_four_chips(topo):
@@ -215,6 +216,7 @@ def test_sharded_solo_bits_compiles_on_four_chips(topo):
         _sds(NamedSharding(mesh, P("data")), (4 * S_CORE,), jnp.uint32),
     ).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "all-gather" in text      # the words, gathered on chip
 
 
 def test_sharded_stacked_gang_compiles_on_four_chips(topo):
@@ -237,3 +239,4 @@ def test_sharded_stacked_gang_compiles_on_four_chips(topo):
              jnp.uint32),
     ).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "all-gather" in text      # the words, gathered on chip

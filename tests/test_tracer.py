@@ -1,12 +1,16 @@
 """The farm's tracer (``repro.serve.tracer``): exact span totals under a
 clock that ticks on every read, the lane counters of each launch shape,
-queue wait at commit, the span names one flush cycle emits, the per-layer
-readers of the benchmark that consume them, and the keys a tiny served
-window of ``farm5.bulk`` carries."""
+queue wait at commit, the span names one flush cycle emits, the mesh
+counters read from where a launch's arrays lie, the per-layer readers of
+the benchmark that consume them, and the keys a tiny served window of
+``farm5.bulk`` carries."""
 import asyncio
 import contextlib
+import json
 import math
+import os
 import pathlib
+import subprocess
 import sys
 
 import jax
@@ -289,3 +293,108 @@ def test_a_tiny_bulk_window_carries_every_new_key():
         assert v is not None and math.isfinite(v), name
     assert _reader("useful_lane_share.bulk")(obs) <= 100.0
     assert st["absorb_words_copied"] > 0
+
+
+# Tier-1 runs on one CPU device: arrays that lie on several devices are
+# made in a subprocess on four forced host devices.
+MESH_SCRIPT = r"""
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from repro.serve.tracer import Tracer
+
+
+class Clock:
+    def now(self):
+        return 0.0
+
+
+devs = jax.devices()[:4]
+MESH = Mesh(np.asarray(devs), ("data",))
+ONE = Mesh(np.asarray(devs[:1]), ("data",))
+x = np.arange(64 * 3, dtype=np.float32).reshape(64, 3)
+arrays = {
+    "one_device": jax.device_put(x, devs[0]),
+    "replicated": jax.device_put(x, NamedSharding(MESH, P())),
+    "sharded": jax.device_put(x, NamedSharding(MESH, P("data"))),
+}
+out = {}
+for name, state in arrays.items():
+    tr = Tracer(Clock())
+    tr.launched(MESH, "data", state)
+    tr.launched(ONE, "data", state)        # a mesh of one is no mesh launch
+    tr.launched(None, "data", state)
+    st = tr.stats()
+    out[name] = {"mesh_launches": st["mesh_launches"],
+                 "mesh_launches_split": st["mesh_launches_split"]}
+for name, words in arrays.items():
+    tr = Tracer(Clock())
+    host = tr.fetch(words)
+    out[name]["fetch_assembled"] = tr.stats()["fetch_assembled"]
+    out[name]["fetched_equal"] = bool(np.array_equal(host, x))
+print("RESULT " + json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def on_a_mesh():
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(ROOT / "src"), str(ROOT)]))
+    p = subprocess.run([sys.executable, "-c", MESH_SCRIPT], env=env,
+                       cwd=ROOT, capture_output=True, text=True, timeout=300)
+    line = [x for x in p.stdout.splitlines() if x.startswith("RESULT ")]
+    assert p.returncode == 0 and line, p.stdout[-2000:] + p.stderr[-4000:]
+    return json.loads(line[-1][len("RESULT "):])
+
+
+@pytest.mark.parametrize("state,split", [("one_device", 0.0),
+                                         ("replicated", 1.0),
+                                         ("sharded", 1.0)])
+def test_a_mesh_launch_is_split_where_its_state_lies(on_a_mesh, state,
+                                                     split):
+    """Only the launch on the four-device mesh counts; it counts as split
+    when its state lies on every device.  The words are no longer read:
+    a sharded launch gathers them onto every device, and a launch whose
+    state stayed on one device ran there, wherever its words were put."""
+    got = on_a_mesh[state]
+    assert got["mesh_launches"] == 1.0
+    assert got["mesh_launches_split"] == split
+
+
+@pytest.mark.parametrize("words,assembled", [("one_device", 0.0),
+                                             ("replicated", 0.0),
+                                             ("sharded", 1.0)])
+def test_a_fetch_is_counted_where_the_host_assembles_its_words(
+        on_a_mesh, words, assembled):
+    got = on_a_mesh[words]
+    assert got["fetch_assembled"] == assembled
+    assert got["fetched_equal"]
+
+
+def test_an_untraced_fetch_counts_nothing():
+    tr = Tracer()
+    words = jax.numpy.arange(6, dtype=jax.numpy.uint32)
+    assert np.array_equal(tr.fetch(words), np.arange(6))
+    assert tr.stats() is None
+
+
+def test_the_fetch_assembled_reader_by_hand():
+    read = _reader("fetch_assembled_share.bulk_mesh4")
+    st = {"fetch_assembled": 3.0, "mesh_launches": 12.0}
+    assert read({"stages": st}) == pytest.approx(25.0)
+    st["fetch_assembled"] = 0.0
+    assert read({"stages": st}) == 0.0
+
+
+@pytest.mark.parametrize("absent", ["fetch_assembled", "mesh_launches"])
+def test_the_fetch_assembled_reader_reads_nothing_without_its_keys(absent):
+    """The parent's program has no ``fetch_assembled``: nothing, not 0."""
+    st = {"fetch_assembled": 0.0, "mesh_launches": 12.0,
+          "mesh_launches_split": 12.0}
+    del st[absent]
+    assert _reader("fetch_assembled_share.bulk_mesh4")({"stages": st}) is None
