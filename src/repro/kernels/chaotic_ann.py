@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 from repro.core.dse import VMEM_USABLE
 
@@ -41,6 +42,12 @@ SUBLANES = 8
 # ``stacked_gang_vmem_bytes`` and the gang planner check against.  Without
 # it Mosaic applies its default 16 MiB scoped limit.
 _COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_USABLE)
+
+# Every single-device launch is jitted with its kernel config static.
+_launch_jit = functools.partial(
+    jax.jit, static_argnames=("n_steps", "s_block", "t_block", "unroll",
+                              "activation", "compute_unit", "lattice",
+                              "interpret"))
 
 
 def _activation(name: str):
@@ -260,6 +267,42 @@ def _whole(a):
     return pl.BlockSpec(a.shape, lambda *_: (0,) * a.ndim)
 
 
+def _carry_loop(x0_ref, state_ref, make_item, *, n_items: int, unroll: int,
+                trips=None):
+    """The carry loop of one grid cell, shared by every kernel here.
+
+    The state block is initialised from ``x0_ref`` at ``t == 0`` and
+    carried in ``state_ref`` across the time grid (the T axis iterates
+    fastest).  ``make_item(t)`` builds ``item(x, base, u) -> x``, which
+    runs item ``base + u`` of time block ``t`` (the item adds the index
+    where its program needs it).  The ``n_items`` items run in
+    ``unroll``-wide chunks — one static chunk when there is only one,
+    else a ``fori_loop`` over the chunks, or over ``trips(t, n_chunks)``
+    of them for a dynamic trip count — and the carry is written back.
+    """
+    t = pl.program_id(1)
+
+    @pl.when(t == 0)
+    def _init():
+        state_ref[...] = x0_ref[...]
+
+    item = make_item(t)
+
+    def chunk(x, base):
+        for u in range(unroll):
+            x = item(x, base, u)
+        return x
+
+    x = state_ref[...]
+    n_chunks = n_items // unroll
+    if trips is None and n_chunks == 1:
+        x = chunk(x, 0)
+    else:
+        n = n_chunks if trips is None else trips(t, n_chunks)
+        x = jax.lax.fori_loop(0, n, lambda c, x: chunk(x, c * unroll), x)
+    state_ref[...] = x
+
+
 def _kernel(*refs, t_block: int, unroll: int, activation: str,
             compute_unit: str, i_dim: int, h_dim: int, lattice, has_cpl):
     """One (stream-block, time-block) grid cell.
@@ -271,45 +314,25 @@ def _kernel(*refs, t_block: int, unroll: int, activation: str,
       x0: (I_pad, s_block)      out: (t_block, I_pad, s_block)
       state (VMEM scratch): (I_pad, s_block)
     """
-    if has_cpl:
-        (w1_ref, b1_ref, w2_ref, b2_ref, cpl_ref, x0_ref, out_ref,
-         state_ref) = refs
-    else:
-        (w1_ref, b1_ref, w2_ref, b2_ref, x0_ref, out_ref, state_ref) = refs
-        cpl_ref = None
-    t = pl.program_id(1)
+    w1_ref, b1_ref, w2_ref, b2_ref, *cpl_ref, x0_ref, out_ref, state_ref = refs
 
-    @pl.when(t == 0)
-    def _init():
-        state_ref[...] = x0_ref[...]
+    def make_item(t):
+        one_step = _make_step(
+            w1_ref[...], b1_ref[...], w2_ref[...], b2_ref[...],
+            activation=activation, compute_unit=compute_unit, i_dim=i_dim,
+            h_dim=h_dim, lattice=lattice,
+            cpl=cpl_ref[0][...] if has_cpl else None)
 
-    one_step = _make_step(w1_ref[...], b1_ref[...], w2_ref[...], b2_ref[...],
-                          activation=activation, compute_unit=compute_unit,
-                          i_dim=i_dim, h_dim=h_dim, lattice=lattice,
-                          cpl=cpl_ref[...] if has_cpl else None)
-
-    def unrolled_chunk(x, base):
-        for u in range(unroll):
+        def item(x, base, u):
             x = one_step(x)
             out_ref[base + u] = x
-        return x
+            return x
+        return item
 
-    x = state_ref[...]
-    n_chunks = t_block // unroll
-    if n_chunks == 1:
-        x = unrolled_chunk(x, 0)
-    else:
-        def body(c, x):
-            return unrolled_chunk(x, c * unroll)
-        x = jax.lax.fori_loop(0, n_chunks, body, x)
-    state_ref[...] = x
+    _carry_loop(x0_ref, state_ref, make_item, n_items=t_block, unroll=unroll)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_steps", "s_block", "t_block", "unroll", "activation",
-                     "compute_unit", "lattice", "interpret"),
-)
+@_launch_jit
 def chaotic_ann_pallas(w1, b1, w2, b2, x0, coupling=None, *, n_steps: int,
                        s_block: int = 256, t_block: int = 128, unroll: int = 1,
                        activation: str = "relu", compute_unit: str = "vpu",
@@ -380,22 +403,28 @@ def chaotic_ann_pallas(w1, b1, w2, b2, x0, coupling=None, *, n_steps: int,
 _GOLDEN = 0x9E3779B9          # Weyl increment (2^32 / phi)
 
 
+def _low_bits(x):
+    """The low mantissa bits of every sample of ``x`` as uint32: bf16 is
+    bitcast at its own width and masked to its 7 mantissa bits (an upcast
+    to f32 would zero the low 16 bits and kill the entropy), f32 keeps
+    its low 16 bits."""
+    if x.dtype.itemsize == 2:
+        u = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+        mask = (1 << jnp.finfo(x.dtype).nmant) - 1
+    else:
+        u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+        mask = 0xFFFF
+    return u & jnp.uint32(mask)
+
+
 def _fold16(x, i_dim: int):
     """Low-mantissa fold of one oscillator sample block.
 
     x: (I_pad, s) floats -> (1, s) uint32, the low mantissa bits of each
     live system dimension XOR-folded with odd shifts.  Bit-exact twin of
-    the per-sample stage of ``ops.bits_from_trajectory`` — including the
-    half-width rule: bf16 is bitcast at its own width and masked to its
-    7 mantissa bits (an upcast to f32 would zero the low 16 bits and kill
-    the entropy).
+    the per-sample stage of ``ops.bits_from_trajectory``.
     """
-    if x.dtype.itemsize == 2:
-        u = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
-        lo = u & jnp.uint32((1 << jnp.finfo(x.dtype).nmant) - 1)
-    else:
-        u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
-        lo = u & jnp.uint32(0xFFFF)
+    lo = _low_bits(x)
     folded = lo[0:1, :]
     for i in range(1, i_dim):
         folded = folded ^ (lo[i:i + 1, :] << jnp.uint32(5 * i % 16))
@@ -412,6 +441,23 @@ def _finalize(w):
     return w
 
 
+def _word_row(one_step, fold, i_dim: int, offs, x, t, rows: int, r):
+    """Two oscillator steps from ``x`` -> one whitened uint32 word row,
+    shared by every word kernel.
+
+    ``fold(sample, i_dim)`` (``_fold16`` or ``_stacked_fold16``) packs a
+    sample's low mantissa bits; the pair is Weyl-whitened by its absolute
+    word row ``t * rows + r`` (row ``r`` of time block ``t``, ``rows``
+    word rows a block) plus the per-lane ``offs``, then finalized.
+    Returns the state after the second step and the word row.
+    """
+    x1 = one_step(x)
+    x2 = one_step(x1)
+    word = (fold(x1, i_dim) << jnp.uint32(16)) | fold(x2, i_dim)
+    row_idx = offs + (t * rows + r).astype(jnp.uint32)
+    return x2, _finalize(word ^ (row_idx * jnp.uint32(_GOLDEN)))
+
+
 def _bits_kernel(*refs, t_block: int, unroll: int,
                  activation: str, compute_unit: str, i_dim: int, h_dim: int,
                  lattice, has_cpl):
@@ -425,49 +471,27 @@ def _bits_kernel(*refs, t_block: int, unroll: int,
              time grid (same output block revisited for every t), so the
              float trajectory is never written to HBM.
     """
-    if has_cpl:
-        (w1_ref, b1_ref, w2_ref, b2_ref, cpl_ref, x0_ref, off_ref,
-         words_ref, state_ref) = refs
-    else:
-        (w1_ref, b1_ref, w2_ref, b2_ref, x0_ref, off_ref,
-         words_ref, state_ref) = refs
-        cpl_ref = None
-    t = pl.program_id(1)
-    rows_per_block = t_block // 2
+    (w1_ref, b1_ref, w2_ref, b2_ref, *cpl_ref, x0_ref, off_ref, words_ref,
+     state_ref) = refs
+    rows = t_block // 2
 
-    @pl.when(t == 0)
-    def _init():
-        state_ref[...] = x0_ref[...]
+    def make_row(t):
+        one_step = _make_step(
+            w1_ref[...], b1_ref[...], w2_ref[...], b2_ref[...],
+            activation=activation, compute_unit=compute_unit, i_dim=i_dim,
+            h_dim=h_dim, lattice=lattice,
+            cpl=cpl_ref[0][...] if has_cpl else None)
+        offs = off_ref[...]
 
-    one_step = _make_step(w1_ref[...], b1_ref[...], w2_ref[...], b2_ref[...],
-                          activation=activation, compute_unit=compute_unit,
-                          i_dim=i_dim, h_dim=h_dim, lattice=lattice,
-                          cpl=cpl_ref[...] if has_cpl else None)
-    offs = off_ref[...]
+        def row(x, base, u):
+            r = base + u
+            x, word = _word_row(one_step, _fold16, i_dim, offs, x, t,
+                                rows, r)
+            words_ref[pl.ds(r, 1), :] = word
+            return x
+        return row
 
-    def one_row(x, r):
-        """Two oscillator steps -> one packed uint32 word row."""
-        x1 = one_step(x)
-        x2 = one_step(x1)
-        word = (_fold16(x1, i_dim) << jnp.uint32(16)) | _fold16(x2, i_dim)
-        row_idx = offs + (t * rows_per_block + r).astype(jnp.uint32)
-        word = word ^ (row_idx * jnp.uint32(_GOLDEN))
-        words_ref[pl.ds(r, 1), :] = _finalize(word)
-        return x2
-
-    def chunk(x, base):
-        for u in range(unroll):
-            x = one_row(x, base + u)
-        return x
-
-    x = state_ref[...]
-    n_chunks = rows_per_block // unroll
-    if n_chunks == 1:
-        x = chunk(x, 0)
-    else:
-        x = jax.lax.fori_loop(0, n_chunks,
-                              lambda c, x: chunk(x, c * unroll), x)
-    state_ref[...] = x
+    _carry_loop(x0_ref, state_ref, make_row, n_items=rows, unroll=unroll)
 
 
 def _bits_blocks(n_steps: int, t_block: int, unroll: int):
@@ -484,11 +508,7 @@ def _bits_blocks(n_steps: int, t_block: int, unroll: int):
     return tb, un
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_steps", "s_block", "t_block", "unroll", "activation",
-                     "compute_unit", "lattice", "interpret"),
-)
+@_launch_jit
 def chaotic_ann_bits_pallas(w1, b1, w2, b2, x0, word_offset=0, coupling=None,
                             *, n_steps: int, s_block: int = 256,
                             t_block: int = 128, unroll: int = 1,
@@ -603,73 +623,45 @@ def _gang_bits_kernel(*refs, t_block: int, unroll: int, activation: str,
     cpl_ref = refs.pop(0) if has_cpl else None
     x0_ref, off_ref, words_ref, state_ref = refs
     g = pl.program_id(0)
-    t = pl.program_id(1)
-    rows_per_block = t_block // 2
+    rows = t_block // 2
 
-    @pl.when(t == 0)
-    def _init():
-        state_ref[...] = x0_ref[...]
+    def make_row(t):
+        one_step = _make_step(
+            w1_ref[0], b1_ref[0], w2_ref[0], b2_ref[0],
+            activation=activation, compute_unit=compute_unit, i_dim=i_dim,
+            h_dim=h_dim, lattice=lattice,
+            cpl=cpl_ref[...] if has_cpl else None)
+        offs = off_ref[...]
 
-    one_step = _make_step(w1_ref[0], b1_ref[0], w2_ref[0], b2_ref[0],
-                          activation=activation, compute_unit=compute_unit,
-                          i_dim=i_dim, h_dim=h_dim, lattice=lattice,
-                          cpl=cpl_ref[...] if has_cpl else None)
-    offs = off_ref[...]
+        def row(x, base, u):
+            r = base + u
+            x, word = _word_row(one_step, _fold16, i_dim, offs, x, t,
+                                rows, r)
+            words_ref[pl.ds(r, 1), :] = word
+            return x
+        return row
 
-    def one_row(x, r):
-        x1 = one_step(x)
-        x2 = one_step(x1)
-        word = (_fold16(x1, i_dim) << jnp.uint32(16)) | _fold16(x2, i_dim)
-        row_idx = offs + (t * rows_per_block + r).astype(jnp.uint32)
-        word = word ^ (row_idx * jnp.uint32(_GOLDEN))
-        words_ref[pl.ds(r, 1), :] = _finalize(word)
-        return x2
+    def trips(t, n_chunks):
+        remaining = jnp.maximum(rmap_ref[g] - t * rows, 0)
+        return jnp.minimum((remaining + unroll - 1) // unroll, n_chunks)
 
-    def chunk(x, base):
-        for u in range(unroll):
-            x = one_row(x, base + u)
-        return x
-
-    x = state_ref[...]
-    n_chunks = rows_per_block // unroll
-    if ragged:
-        remaining = jnp.maximum(rmap_ref[g] - t * rows_per_block, 0)
-        active = jnp.minimum((remaining + unroll - 1) // unroll, n_chunks)
-        x = jax.lax.fori_loop(0, active,
-                              lambda c, x: chunk(x, c * unroll), x)
-    elif n_chunks == 1:
-        x = chunk(x, 0)
-    else:
-        x = jax.lax.fori_loop(0, n_chunks,
-                              lambda c, x: chunk(x, c * unroll), x)
-    state_ref[...] = x
-
-
-def gang_row_granularity(n_steps: int, t_block: int, unroll: int) -> int:
-    """Word-row granularity of ragged early-out in the lane-concat kernel.
-
-    The dynamic row loop skips whole unroll-chunks, so a block's computed
-    rows are its ``row_map`` entry rounded up to the post-gcd unroll (the
-    same ``_bits_blocks`` collapse the kernel itself applies).
-    """
-    _, un = _bits_blocks(n_steps, t_block, unroll)
-    return un
+    _carry_loop(x0_ref, state_ref, make_row, n_items=rows, unroll=unroll,
+                trips=trips if ragged else None)
 
 
 def gang_effective_rows(row_map, n_steps: int, t_block: int,
                         unroll: int) -> np.ndarray:
     """Word rows each lane block of a ragged gang launch actually computes
-    (and therefore the rows its member's state/counters advance by)."""
-    un = gang_row_granularity(n_steps, t_block, unroll)
+    (and therefore the rows its member's state/counters advance by): the
+    dynamic row loop skips whole unroll-chunks, so a block's computed rows
+    are its ``row_map`` entry rounded up to the post-gcd unroll (the same
+    ``_bits_blocks`` collapse the kernel itself applies)."""
+    _, un = _bits_blocks(n_steps, t_block, unroll)
     r = np.asarray(row_map, np.int64)
     return np.minimum(-(-r // un) * un, n_steps // 2).astype(np.int32)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_steps", "s_block", "t_block", "unroll", "activation",
-                     "compute_unit", "lattice", "interpret"),
-)
+@_launch_jit
 def chaotic_ann_gang_bits_pallas(w1, b1, w2, b2, x0, core_map, word_offset=0,
                                  row_map=None, coupling=None, *, n_steps: int,
                                  s_block: int = 256,
@@ -698,12 +690,9 @@ def chaotic_ann_gang_bits_pallas(w1, b1, w2, b2, x0, core_map, word_offset=0,
       word_offset: scalar or (S,) uint32 per-lane word-row offsets.
       row_map: optional (n_blocks,) int array — word rows each lane block
         owes (demand-shaped launch).  Block ``g`` computes exactly
-        ``gang_effective_rows(row_map, ...)[g]`` rows (its demand rounded
-        up to the unroll-chunk granularity) and its state advances by that
-        many rows; word rows past it are unwritten garbage.  Per lane the
-        computed prefix is bit-identical to a per-core launch of that many
-        rows (absolute-row Weyl indexing).  None = every block computes
-        all ``n_steps // 2`` rows (the padded group-max launch).
+        ``gang_effective_rows(row_map, ...)[g]`` rows, bit-identical to a
+        per-core launch of that many rows; later rows are unwritten
+        garbage.  None = every block computes all ``n_steps // 2`` rows.
       coupling / lattice: see ``chaotic_ann_pallas``.  ONE coupling operand
         is shared by every lane block — a gang only admits cores with
         identical lattice meta (the scheduler's compat key), so the shared
@@ -805,12 +794,7 @@ def _stacked_fold16(x, i_dim: int):
     chain of (C, s) values — the same low-mantissa bits, shifts, and order
     per lane as ``_fold16`` on each core alone.
     """
-    if x.dtype.itemsize == 2:
-        u = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
-        lo = u & jnp.uint32((1 << jnp.finfo(x.dtype).nmant) - 1)
-    else:
-        u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
-        lo = u & jnp.uint32(0xFFFF)
+    lo = _low_bits(x)
     folded = lo[0]
     for i in range(1, i_dim):
         folded = folded ^ (lo[i] << jnp.uint32(5 * i % 16))
@@ -864,59 +848,32 @@ def _gang_stacked_kernel(*refs, t_block: int, unroll: int,
     state (and word prefix) is bit-identical to a per-core launch of that
     many rows.  Word rows past a core's demand are garbage.
     """
-    if ragged:
-        (w1t_ref, b1_ref, w2t_ref, b2_ref, x0_ref, off_ref, rmap_ref,
-         words_ref, state_ref) = refs
-    else:
-        (w1t_ref, b1_ref, w2t_ref, b2_ref, x0_ref, off_ref,
-         words_ref, state_ref) = refs
-        rmap_ref = None
-    t = pl.program_id(1)
-    rows_per_block = t_block // 2
+    (w1t_ref, b1_ref, w2t_ref, b2_ref, x0_ref, off_ref, *rmap_ref, words_ref,
+     state_ref) = refs
+    rows = t_block // 2
 
-    @pl.when(t == 0)
-    def _init():
-        state_ref[...] = x0_ref[...]
+    def make_row(t):
+        one_step = _make_stacked_step(
+            w1t_ref[...], b1_ref[...], w2t_ref[...], b2_ref[...],
+            activation=activation, i_dim=i_dim, h_dim=h_dim, lattice=lattice)
+        offs = off_ref[...]
+        rmap = rmap_ref[0][...] if ragged else None
 
-    one_step = _make_stacked_step(
-        w1t_ref[...], b1_ref[...], w2t_ref[...], b2_ref[...],
-        activation=activation, i_dim=i_dim, h_dim=h_dim, lattice=lattice)
-    offs = off_ref[...]
-    rmap = rmap_ref[...] if ragged else None
+        def row(x, base, u):
+            r = base + u
+            x2, word = _word_row(one_step, _stacked_fold16, i_dim, offs,
+                                 x, t, rows, r)
+            words_ref[pl.ds(r, 1), :, :] = word[None]
+            if ragged:
+                alive = (t * rows + r) < rmap                # (C, 1) bool
+                x2 = jnp.where(alive[None], x2, x)
+            return x2
+        return row
 
-    def one_row(x, r):
-        x1 = one_step(x)
-        x2 = one_step(x1)
-        word = ((_stacked_fold16(x1, i_dim) << jnp.uint32(16))
-                | _stacked_fold16(x2, i_dim))
-        row_idx = offs + (t * rows_per_block + r).astype(jnp.uint32)
-        word = word ^ (row_idx * jnp.uint32(_GOLDEN))
-        words_ref[pl.ds(r, 1), :, :] = _finalize(word)[None]
-        if ragged:
-            alive = (t * rows_per_block + r) < rmap          # (C, 1) bool
-            x2 = jnp.where(alive[None], x2, x)
-        return x2
-
-    def chunk(x, base):
-        for u in range(unroll):
-            x = one_row(x, base + u)
-        return x
-
-    x = state_ref[...]
-    n_chunks = rows_per_block // unroll
-    if n_chunks == 1:
-        x = chunk(x, 0)
-    else:
-        x = jax.lax.fori_loop(0, n_chunks,
-                              lambda c, x: chunk(x, c * unroll), x)
-    state_ref[...] = x
+    _carry_loop(x0_ref, state_ref, make_row, n_items=rows, unroll=unroll)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("n_steps", "s_block", "t_block", "unroll", "activation",
-                     "compute_unit", "lattice", "interpret"),
-)
+@_launch_jit
 def chaotic_ann_gang_stacked_pallas(w1, b1, w2, b2, x0, word_offset=0,
                                     row_map=None, *,
                                     n_steps: int, s_block: int = 256,
@@ -927,31 +884,22 @@ def chaotic_ann_gang_stacked_pallas(w1, b1, w2, b2, x0, word_offset=0,
     """Gang launch for C equal-shape pools, stacked on the SUBLANE axis.
 
     Where ``chaotic_ann_gang_bits_pallas`` concatenates pools along the
-    lane axis (one grid cell per member lane block), this variant exploits
-    equal pool shapes to stack the group: state is (I, C, s_block) in one
-    grid cell — dimension-major, the C cores on sublanes — and each update
-    step is ONE broadcast-FMA sweep over the stacked group — C networks
-    advance for the per-cell cost of one.  This is the paper's
-    parallelism-P MAC array applied across *cores* instead of across
-    streams, and it is what makes small gang flushes cheaper than C small
-    per-core flushes (per-launch and per-grid-cell overheads are paid
-    once, not C times).
-
-    Per lane the FMA accumulation order, bit fold, and whitening are
-    identical to the per-core kernel, so words and final states are
+    lane axis, this variant stacks equal pools: state is (I, C, s_block)
+    in one grid cell — dimension-major, the C cores on sublanes — and each
+    step is ONE broadcast-FMA sweep over the group, so C networks advance
+    for the per-cell cost of one (the paper's parallelism-P MAC array
+    applied across *cores*).  Per lane the FMA order, bit fold and
+    whitening are the per-core kernel's, so words and final states are
     bit-identical to C ``chaotic_ann_bits_pallas`` launches.
 
     Args:
       w1 (C, I, H), b1 (C, H), w2 (C, H, I), b2 (C, I): stacked weights.
       x0 (C, S, I): one equal-size pool per core.
       word_offset: scalar or (C, S) uint32 per-lane word-row offsets.
-      row_map: optional (C,) int array of per-core word-row demands.  The
-        stacked sweep still advances the whole group together (no FMA
-        saved — the stack is one fused op), but core ``c``'s state is
-        frozen after exactly ``row_map[c]`` rows, so its final state and
-        its ``words[:row_map[c]]`` prefix are bit-identical to a per-core
-        launch of ``2 * row_map[c]`` steps; later word rows are garbage.
-        None = every core computes all rows (the padded group-max launch).
+      row_map: optional (C,) int array of per-core word-row demands: core
+        ``c``'s state freezes after ``row_map[c]`` rows (no FMA saved), so
+        its state and word prefix are bit-identical to a per-core launch
+        of that many rows; later rows are garbage.  None = all rows.
     Returns:
       words: (n_steps // 2, C, S) uint32, final_state: (C, S, I).
     """
@@ -1074,6 +1022,37 @@ def _gather_lanes(words: jax.Array, mesh_axis: str) -> jax.Array:
                               tiled=True)
 
 
+@functools.lru_cache(maxsize=384)
+def _sharded_launch(launch, in_specs, state_spec, mesh, mesh_axis, **kw):
+    """The one builder of every sharded launch: the single-device
+    ``launch`` inside a jitted ``shard_map`` over ``mesh[mesh_axis]``.
+
+    ``in_specs`` has a spec per positional argument of ``launch``, None
+    for an optional argument it is not given (the caller passes None,
+    which adds no operand), so the specs key the program's operands too.
+    Weights, coupling and pool are traced arguments — closed over, they
+    would be baked in as constants and recompile every flush — so the
+    callable, cached per (launch, specs, mesh, static kernel config
+    ``kw``), retraces only for a new launch SHAPE.  Each device runs
+    ``launch`` on its own run of lanes; its words are all-gathered into
+    one replicated slab (``_gather_lanes``), the state keeps ``state_spec``.
+    """
+    def local(*args):
+        words, state = launch(*args, **kw)
+        return _gather_lanes(words, mesh_axis), state
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=(P(), state_spec),
+        check_vma=False))
+
+
+def sharded_launch_builds() -> int:
+    """Sharded launch callables built so far in this process, solo and
+    gang: the misses of ``_sharded_launch``'s cache.  A launch that
+    raises this count built (and will compile) a new program."""
+    return _sharded_launch.cache_info().misses
+
+
 def chaotic_ann_bits_sharded(w1, b1, w2, b2, x0, offsets, coupling=None,
                              *, mesh, mesh_axis: str = "data",
                              n_steps: int, s_block: int = 256,
@@ -1085,48 +1064,30 @@ def chaotic_ann_bits_sharded(w1, b1, w2, b2, x0, offsets, coupling=None,
 
     The pool's stream axis and its (S,) per-lane word offsets shard on
     the named axis; the weights (and an mxu lattice's coupling operand)
-    are replicated as traced arguments.  Each device runs
-    ``chaotic_ann_bits_pallas`` on its contiguous run of lanes, so the
-    words are bit-identical to the unsharded launch.  The words come
-    back whole on every device (gathered over the mesh inside the same
-    program); the final state stays sharded on its stream axis, where
-    the next launch reads it.  The shard_map'd callable is cached per
-    (mesh, static config) and jitted, as the gang variants are.  The
-    pool must divide the device count: ``ops.chaotic_bits`` pads it with
-    dead lanes.
+    are replicated.  Each device runs ``chaotic_ann_bits_pallas`` on its
+    lanes, so the words are bit-identical to the unsharded launch; they
+    come back whole on every device and the final state stays sharded on
+    its stream axis (``_sharded_launch``).  The pool must divide the
+    device count: ``ops.chaotic_bits`` pads it with dead lanes.
     """
-    args = [w1, b1, w2, b2, x0, offsets]
-    if coupling is not None:
-        args.append(jnp.asarray(coupling))
-    fn = _sharded_bits_fn(mesh, mesh_axis, n_steps, s_block, t_block, unroll,
-                          activation, compute_unit, lattice,
-                          coupling is not None, interpret)
-    return fn(*args)
+    cpl = None if coupling is None else jnp.asarray(coupling)
+    fn = _sharded_launch(
+        chaotic_ann_bits_pallas,
+        (P(),) * 4 + (P(mesh_axis, None), P(mesh_axis),
+                      None if cpl is None else P()),
+        P(mesh_axis, None), mesh, mesh_axis, n_steps=n_steps,
+        s_block=s_block, t_block=t_block, unroll=unroll,
+        activation=activation, compute_unit=compute_unit, lattice=lattice,
+        interpret=interpret)
+    return fn(w1, b1, w2, b2, x0, offsets, cpl)
 
 
-@functools.lru_cache(maxsize=128)
-def _sharded_bits_fn(mesh, mesh_axis, n_steps, s_block, t_block, unroll,
-                     activation, compute_unit, lattice, has_cpl, interpret):
-    """Jitted shard_map'd solo bits launch, cached per (mesh, static
-    kernel config) — see ``_sharded_gang_bits_fn``."""
-    from jax.sharding import PartitionSpec as P
-
-    kw = dict(n_steps=n_steps, s_block=s_block, t_block=t_block,
-              unroll=unroll, activation=activation,
-              compute_unit=compute_unit, lattice=lattice,
-              interpret=interpret)
-    in_specs = [P(), P(), P(), P(), P(mesh_axis, None), P(mesh_axis)]
-    if has_cpl:
-        in_specs.append(P())
-
-    def local(w1, b1, w2, b2, x_l, off_l, *cpl):
-        words, state = chaotic_ann_bits_pallas(
-            w1, b1, w2, b2, x_l, off_l, cpl[0] if cpl else None, **kw)
-        return _gather_lanes(words, mesh_axis), state
-
-    return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=(P(), P(mesh_axis, None)), check_vma=False))
+def _lane_concat_launch(w1, b1, w2, b2, x0, word_offset, core_map, row_map,
+                        coupling, **kw):
+    """``chaotic_ann_gang_bits_pallas`` with the offsets before the maps,
+    the operand order of its sharded program."""
+    return chaotic_ann_gang_bits_pallas(w1, b1, w2, b2, x0, core_map,
+                                        word_offset, row_map, coupling, **kw)
 
 
 def chaotic_ann_gang_bits_sharded(w1, b1, w2, b2, x0, core_map,
@@ -1139,20 +1100,14 @@ def chaotic_ann_gang_bits_sharded(w1, b1, w2, b2, x0, core_map,
                                   lattice=None, interpret: bool = False):
     """Lane-concat gang launch partitioned across ``mesh[mesh_axis]``.
 
-    Weight slabs are replicated (passed through with ``P()`` specs — NOT
-    closed over, which would bake them into the trace as constants and
-    defeat the jit cache, recompiling every flush); the pooled stream
-    axis and BOTH scalar-prefetch maps shard on the named axis, so each
-    device runs the single-device gang kernel on its own contiguous run
-    of lane blocks with its *own slice* of the core-id/row maps.  Lanes
-    evolve independently and word whitening is indexed by absolute
-    per-lane row offsets, so the result is bit-identical to the
-    unsharded gang launch (and hence to per-core launches) at any device
-    count.  The words come back whole on every device (gathered over the
-    mesh inside the same program); the final state stays sharded on its
-    stream axis.  The shard_map'd callable is cached per (mesh, static
-    config) and jitted, so steady-state flushes reuse one compiled
-    program per launch shape.
+    Weight slabs (and an mxu lattice's coupling operand) are replicated;
+    the pooled stream axis and BOTH scalar-prefetch maps shard on the
+    named axis, so each device runs the gang kernel on its own run of
+    lane blocks with its *own slice* of the maps.  Lanes evolve
+    independently and whitening is indexed by absolute per-lane rows, so
+    the result is bit-identical to the unsharded gang launch at any
+    device count.  The words come back whole on every device; the final
+    state stays sharded on its stream axis (``_sharded_launch``).
 
     The block axis must divide the device count — pad the maps (and the
     pool) with ``gang_partition_maps`` dead blocks first.
@@ -1166,56 +1121,24 @@ def chaotic_ann_gang_bits_sharded(w1, b1, w2, b2, x0, core_map,
             f"axis {mesh_axis!r}; pad the maps with gang_partition_maps")
     s_total = x0.shape[0]
     off = jnp.broadcast_to(jnp.asarray(word_offset, jnp.uint32), (s_total,))
-
-    args = [w1, b1, w2, b2, x0, off, cmap]
-    if row_map is not None:
-        args.append(jnp.asarray(row_map, jnp.int32))
-    has_cpl = lattice is not None and compute_unit == "mxu"
-    if has_cpl:
+    rmap = None if row_map is None else jnp.asarray(row_map, jnp.int32)
+    cpl = None
+    if lattice is not None and compute_unit == "mxu":
         if coupling is None:
             raise ValueError(
                 "mxu lattice launches need the dense coupling operand")
-        args.append(jnp.asarray(coupling))
-    fn = _sharded_gang_bits_fn(
-        mesh, mesh_axis, row_map is not None, n_steps, s_block, t_block,
-        unroll, activation, compute_unit, lattice, has_cpl, interpret)
-    return fn(*args)
-
-
-@functools.lru_cache(maxsize=128)
-def _sharded_gang_bits_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
-                          t_block, unroll, activation, compute_unit,
-                          lattice, has_cpl, interpret):
-    """Jitted shard_map'd lane-concat gang launch, cached per (mesh,
-    static kernel config).  Weights/pool/maps are traced arguments, so
-    jit retraces only when a launch SHAPE is new — per-flush weight or
-    demand values hit the compiled program.  Each device's words are
-    all-gathered into one replicated slab (``_gather_lanes``); the state
-    keeps its lane sharding."""
-    from jax.sharding import PartitionSpec as P
-
-    kw = dict(n_steps=n_steps, s_block=s_block, t_block=t_block,
-              unroll=unroll, activation=activation,
-              compute_unit=compute_unit, lattice=lattice,
-              interpret=interpret)
-    in_specs = [P(), P(), P(), P(),
-                P(mesh_axis, None), P(mesh_axis), P(mesh_axis)]
-    if has_rmap:
-        in_specs.append(P(mesh_axis))
-    if has_cpl:
-        in_specs.append(P())       # coupling: replicated like the weights
-
-    def local(w1, b1, w2, b2, x_l, off_l, cmap_l, *rest):
-        rest = list(rest)
-        rmap_l = rest.pop(0) if has_rmap else None
-        cpl = rest.pop(0) if has_cpl else None
-        words, state = chaotic_ann_gang_bits_pallas(
-            w1, b1, w2, b2, x_l, cmap_l, off_l, rmap_l, cpl, **kw)
-        return _gather_lanes(words, mesh_axis), state
-
-    return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=(P(), P(mesh_axis, None)), check_vma=False))
+        cpl = jnp.asarray(coupling)
+    lanes = P(mesh_axis)
+    fn = _sharded_launch(
+        _lane_concat_launch,
+        (P(),) * 4 + (P(mesh_axis, None), lanes, lanes,
+                      None if rmap is None else lanes,
+                      None if cpl is None else P()),
+        P(mesh_axis, None), mesh, mesh_axis, n_steps=n_steps,
+        s_block=s_block, t_block=t_block, unroll=unroll,
+        activation=activation, compute_unit=compute_unit, lattice=lattice,
+        interpret=interpret)
+    return fn(w1, b1, w2, b2, x0, off, cmap, rmap, cpl)
 
 
 def chaotic_ann_gang_stacked_sharded(w1, b1, w2, b2, x0, word_offset=0,
@@ -1230,15 +1153,11 @@ def chaotic_ann_gang_stacked_sharded(w1, b1, w2, b2, x0, word_offset=0,
 
     The group's equal-size pools shard on the STREAM axis (every device
     keeps all C cores stacked on sublanes, with 1/n_dev of each pool's
-    lanes); the (C,) row map is replicated since a core's freeze row is
-    lane-independent.  The words come back whole on every device
-    (gathered over the mesh inside the same program); the final state
-    stays sharded on its stream axis.  Weight tables are replicated as
-    traced arguments
-    (``P()`` specs), and the shard_map'd callable is cached per (mesh,
-    static config) + jitted — same no-recompile-per-flush discipline as
-    the lane-concat variant.  The pool size must divide the device
-    count; ragged pool sizes take the lane-concat sharded path instead.
+    lanes); the weight tables and the (C,) row map are replicated, since
+    a core's freeze row is lane-independent.  The words come back whole
+    on every device; the final state stays sharded on its stream axis
+    (``_sharded_launch``).  The pool size must divide the device count;
+    ragged pool sizes take the lane-concat sharded path instead.
     """
     n_dev = int(mesh.shape[mesh_axis])
     n_cores, s_total = x0.shape[0], x0.shape[1]
@@ -1248,46 +1167,13 @@ def chaotic_ann_gang_stacked_sharded(w1, b1, w2, b2, x0, word_offset=0,
             f"devices on mesh axis {mesh_axis!r}")
     off = jnp.broadcast_to(jnp.asarray(word_offset, jnp.uint32),
                            (n_cores, s_total))
-
-    args = [w1, b1, w2, b2, x0, off]
-    if row_map is not None:
-        args.append(jnp.asarray(row_map, jnp.int32))
-    fn = _sharded_gang_stacked_fn(
-        mesh, mesh_axis, row_map is not None, n_steps, s_block, t_block,
-        unroll, activation, compute_unit, lattice, interpret)
-    return fn(*args)
-
-
-@functools.lru_cache(maxsize=128)
-def _sharded_gang_stacked_fn(mesh, mesh_axis, has_rmap, n_steps, s_block,
-                             t_block, unroll, activation, compute_unit,
-                             lattice, interpret):
-    """Jitted shard_map'd sublane-stacked gang launch, cached per (mesh,
-    static kernel config) — see ``_sharded_gang_bits_fn``."""
-    from jax.sharding import PartitionSpec as P
-
-    kw = dict(n_steps=n_steps, s_block=s_block, t_block=t_block,
-              unroll=unroll, activation=activation,
-              compute_unit=compute_unit, lattice=lattice,
-              interpret=interpret)
-    in_specs = [P(), P(), P(), P(),
-                P(None, mesh_axis, None), P(None, mesh_axis)]
-    if has_rmap:
-        in_specs.append(P())            # (C,) freeze rows: lane-independent
-
-    def local(w1, b1, w2, b2, x_l, off_l, *rmap):
-        words, state = chaotic_ann_gang_stacked_pallas(
-            w1, b1, w2, b2, x_l, off_l, rmap[0] if rmap else None, **kw)
-        return _gather_lanes(words, mesh_axis), state
-
-    return jax.jit(jax.shard_map(
-        local, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=(P(), P(None, mesh_axis, None)), check_vma=False))
-
-
-def sharded_launch_builds() -> int:
-    """Sharded launch callables built so far in this process, solo and
-    gang: the misses of their builders' caches.  A launch that raises
-    this count built (and will compile) a new program."""
-    return sum(f.cache_info().misses for f in (
-        _sharded_bits_fn, _sharded_gang_bits_fn, _sharded_gang_stacked_fn))
+    rmap = None if row_map is None else jnp.asarray(row_map, jnp.int32)
+    fn = _sharded_launch(
+        chaotic_ann_gang_stacked_pallas,
+        (P(),) * 4 + (P(None, mesh_axis, None), P(None, mesh_axis),
+                      None if rmap is None else P()),
+        P(None, mesh_axis, None), mesh, mesh_axis, n_steps=n_steps,
+        s_block=s_block, t_block=t_block, unroll=unroll,
+        activation=activation, compute_unit=compute_unit, lattice=lattice,
+        interpret=interpret)
+    return fn(w1, b1, w2, b2, x0, off, rmap)

@@ -103,6 +103,23 @@ def test_pack_words_matches_bits_from_trajectory():
                                   np.asarray(base[:, 0]))  # offset 0 column
 
 
+def test_pack_words_packs_any_stream_layout():
+    """One stream's (T, I) samples and a stacked (T, C, S, I) pool pack
+    as the same streams do in a (T, S, I) pool, offsets included."""
+    w1, b1, w2, b2, x0 = _mk(3, 8, 64)
+    traj = chaotic_ann_ref(w1, b1, w2, b2, x0, 32)
+    off = jnp.arange(64, dtype=jnp.uint32) * 7
+    flat = np.asarray(pack_words(traj, off))
+    one = pack_words(traj[:, 5], off[5])
+    assert one.shape == (16,)
+    np.testing.assert_array_equal(np.asarray(one), flat[:, 5])
+    stacked = pack_words(traj.reshape(32, 4, 16, 3), off.reshape(4, 16))
+    np.testing.assert_array_equal(np.asarray(stacked).reshape(16, 64), flat)
+    np.testing.assert_array_equal(
+        np.asarray(bits_from_trajectory(traj.reshape(32, 4, 16, 3))),
+        np.asarray(pack_words(traj, 0)).reshape(16, 4, 16))
+
+
 def test_uniform_from_trajectory_signature_and_range():
     """Regression: the dead (ignored) `scale_bits` parameter is gone — the
     signature no longer advertises a knob that does nothing."""

@@ -72,6 +72,13 @@ def _kw(c: Candidate):
                 unroll=c.unroll, compute_unit=c.compute_unit)
 
 
+def _sharded_kw(c: Candidate):
+    """``ca._sharded_launch``'s static kernel config for ``c``."""
+    return dict(n_steps=N_STEPS, s_block=c.s_block, t_block=c.t_block,
+                unroll=c.unroll, activation="relu",
+                compute_unit=c.compute_unit, lattice=None, interpret=False)
+
+
 def _compile(fn, *args):
     text = jax.jit(fn).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text      # the Pallas kernel, not a fallback
@@ -188,12 +195,14 @@ def test_sharded_lane_concat_gang_compiles_on_four_chips(topo):
     lanes = (NamedSharding(mesh, P("data", None)),
              NamedSharding(mesh, P("data")))
     n_blocks = 4 * S_CORE // c.s_block
-    fn = ca._sharded_gang_bits_fn(
-        mesh, "data", True, N_STEPS, c.s_block, c.t_block, c.unroll,
-        "relu", c.compute_unit, None, False, False)
+    fn = ca._sharded_launch(
+        ca._lane_concat_launch,
+        (P(),) * 4 + (P("data", None), P("data"), P("data"), P("data"),
+                      None),
+        P("data", None), mesh, "data", **_sharded_kw(c))
     args = _concat_args(rep, c, 4, n_blocks, jnp.bfloat16,
                         maps_sharding=lanes)
-    text = fn.lower(*args).compile().as_text()
+    text = fn.lower(*args, None).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text      # the words, gathered on chip
 
@@ -206,14 +215,16 @@ def test_sharded_solo_bits_compiles_on_four_chips(topo):
     mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
     rep = NamedSharding(mesh, P())
     i, h, dt = c.i_dim, c.h_dim, jnp.bfloat16
-    fn = ca._sharded_bits_fn(mesh, "data", N_STEPS, c.s_block, c.t_block,
-                             c.unroll, "relu", c.compute_unit, None, False,
-                             False)
+    fn = ca._sharded_launch(
+        ca.chaotic_ann_bits_pallas,
+        (P(),) * 4 + (P("data", None), P("data"), None),
+        P("data", None), mesh, "data", **_sharded_kw(c))
     text = fn.lower(
         _sds(rep, (i, h), dt), _sds(rep, (h,), dt), _sds(rep, (h, i), dt),
         _sds(rep, (i,), dt),
         _sds(NamedSharding(mesh, P("data", None)), (4 * S_CORE, i), dt),
         _sds(NamedSharding(mesh, P("data")), (4 * S_CORE,), jnp.uint32),
+        None,
     ).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text      # the words, gathered on chip
@@ -227,9 +238,10 @@ def test_sharded_stacked_gang_compiles_on_four_chips(topo):
     mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
     rep = NamedSharding(mesh, P())
     n, i, h, dt = 4, c.i_dim, c.h_dim, jnp.bfloat16
-    fn = ca._sharded_gang_stacked_fn(
-        mesh, "data", False, N_STEPS, c.s_block, c.t_block, c.unroll,
-        "relu", c.compute_unit, None, False)
+    fn = ca._sharded_launch(
+        ca.chaotic_ann_gang_stacked_pallas,
+        (P(),) * 4 + (P(None, "data", None), P(None, "data"), None),
+        P(None, "data", None), mesh, "data", **_sharded_kw(c))
     text = fn.lower(
         _sds(rep, (n, i, h), dt), _sds(rep, (n, h), dt),
         _sds(rep, (n, h, i), dt), _sds(rep, (n, i), dt),
@@ -237,6 +249,7 @@ def test_sharded_stacked_gang_compiles_on_four_chips(topo):
              dt),
         _sds(NamedSharding(mesh, P(None, "data")), (n, 4 * S_CORE),
              jnp.uint32),
+        None,
     ).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text      # the words, gathered on chip
