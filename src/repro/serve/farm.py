@@ -35,11 +35,13 @@ Cores come from two places:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import pathlib
-from typing import Dict, Iterable, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -106,6 +108,19 @@ def _compat_key(svc: PRNGService) -> Optional[Tuple]:
             svc.activation, svc.backend,
             c.s_block, c.t_block, c.unroll, c.compute_unit,
             _lattice_sig(svc), _topology(svc))
+
+
+@dataclasses.dataclass(eq=False)
+class _Dispatched:
+    """One launch of a flush, dispatched and not yet fetched: what its
+    finish half needs to hand each member its words and final state."""
+    words: jax.Array          # on the device until fetched
+    members: List[Tuple]      # (core, service, rows needed, offsets)
+    rows: List[int]           # word rows each member takes
+    cuts: List[Tuple]         # each member's (index into words, state)
+    computed: int             # lane-rows the kernel computed
+    plan: Optional[Dict] = None       # a gang's plan: its x0 cache
+    state: Optional[jax.Array] = None  # a gang's whole final state
 
 
 class GangScheduler:
@@ -352,10 +367,10 @@ class GangScheduler:
                 parts.append(pad)
         return jnp.concatenate(parts, axis=0)
 
-    def _launch_group(self, key: Tuple, members: Sequence[Tuple],
-                      demands: Sequence[int], *, layout: str, ragged: bool,
-                      deliver: bool) -> Dict[str, Dict[str, np.ndarray]]:
-        """One gang launch (padded or ragged) for ``members``."""
+    def _dispatch_group(self, key: Tuple, members: Sequence[Tuple],
+                        demands: Sequence[int], *, layout: str,
+                        ragged: bool) -> _Dispatched:
+        """Dispatch one gang launch (padded or ragged) for ``members``."""
         from repro.kernels import ops
         from repro.kernels.chaotic_ann import gang_effective_rows
         if self.faults is not None:
@@ -417,54 +432,96 @@ class GangScheduler:
                 backend=svc0.backend, mesh=svc0.mesh,
                 mesh_axis=svc0.mesh_axis, config=cfg)
             tr.launched(svc0.mesh, svc0.mesh_axis, state)
-            words = tr.fetch(words)
             if layout == "stacked":
-                handed = [state[ci] for ci in range(len(members))]
-                member_out = [(words[:member_rows[ci], ci, :], handed[ci])
-                              for ci in range(len(members))]
+                cuts = [(np.s_[:member_rows[ci], ci, :], state[ci])
+                        for ci in range(len(members))]
             else:
-                handed = [state[start:start + live]
-                          for (start, live, _) in plan["spans"]]
-                member_out = [(words[:member_rows[ci], start:start + live],
-                               handed[ci])
-                              for ci, (start, live, _)
-                              in enumerate(plan["spans"])]
-            plan["last_x"], plan["handed"] = state, handed
+                cuts = [(np.s_[:member_rows[ci], start:start + live],
+                         state[start:start + live])
+                        for ci, (start, live, _) in enumerate(plan["spans"])]
             self.launches += 1
             self.layouts[layout] += 1
             # ragged and padded launches of the same shape are distinct
             # jit traces (row_map None vs array), hence distinct dispatch
             # keys
             self._dispatch_keys.add((plan["sig"], n_rows, bool(ragged)))
-        used = self._used_lanes(member_rows,
-                                [svc for _, svc, _, _ in members])
-        out: Dict[str, Dict[str, np.ndarray]] = {}
-        with tr.span("farm.absorb", "absorb"):
-            for (mwords, mstate), rows_c, (name, svc, _, _) in zip(
-                    member_out, member_rows, members):
-                served = svc.absorb(mwords, mstate, rows_c, deliver=deliver)
-                if served:
-                    out[name] = served
-        tr.count(lanes_computed=computed, lanes_used=used)
-        return out
+        return _Dispatched(words, list(members), member_rows, cuts, computed,
+                           plan, state)
 
-    def _launch_solo(self, member: Tuple, n_rows: int, *,
-                     deliver: bool) -> Dict[str, Dict[str, np.ndarray]]:
-        """One core's own launch: a planner-split singleton, a core that
-        gangs with no other, or every core of a ``gang=False`` farm."""
+    def _dispatch_solo(self, member: Tuple, n_rows: int) -> _Dispatched:
+        """Dispatch one core's own launch: a planner-split singleton, a
+        core that gangs with no other, or every core of a ``gang=False``
+        farm."""
         name, svc, _, offsets = member
         if self.faults is not None:
             self.faults.on_launch([name])
-        tr = self.tracer
-        with tr.span("farm.launch", "launch"):
-            words, new_x = svc._launch(n_rows, jnp.asarray(offsets))
+        with self.tracer.span("farm.launch", "launch"):
+            words, new_x = svc._dispatch(n_rows, jnp.asarray(offsets))
         s_blk = svc.config.s_block
         computed = n_rows * (-(-svc.pool_x.shape[0] // s_blk) * s_blk)
-        used = self._used_lanes([n_rows], [svc])
+        return _Dispatched(words, [member], [n_rows], [(np.s_[:], new_x)],
+                           computed)
+
+    def _finish(self, d: _Dispatched, *, deliver: bool,
+                then: Optional[_Dispatched] = None
+                ) -> Dict[str, Dict[str, np.ndarray]]:
+        """Fetch a dispatched launch's words, start the copy of ``then``
+        (the flush's next launch) as soon as they have landed, and absorb
+        each member's words and final state."""
+        tr = self.tracer
+        with tr.span("farm.launch", "launch"):
+            words = tr.fetch(d.words, overlapped=then is not None)
+            if then is not None:
+                then.words.copy_to_host_async()
+        if d.plan is not None:
+            d.plan["last_x"] = d.state
+            d.plan["handed"] = [state for _, state in d.cuts]
+        used = self._used_lanes(d.rows, [svc for _, svc, _, _ in d.members])
+        out: Dict[str, Dict[str, np.ndarray]] = {}
         with tr.span("farm.absorb", "absorb"):
-            served = svc.absorb(words, new_x, n_rows, deliver=deliver)
-        tr.count(lanes_computed=computed, lanes_used=used)
-        return {name: served} if served else {}
+            for (index, state), rows, (name, svc, _, _) in zip(
+                    d.cuts, d.rows, d.members):
+                served = svc.absorb(words[index], state, rows,
+                                    deliver=deliver)
+                if served:
+                    out[name] = served
+        tr.count(lanes_computed=d.computed, lanes_used=used)
+        return out
+
+    def _finish_all(self, launched: List[_Dispatched], *, deliver: bool
+                    ) -> Dict[str, Dict[str, np.ndarray]]:
+        out: Dict[str, Dict[str, np.ndarray]] = {}
+        for d, then in zip(launched, launched[1:] + [None]):
+            out.update(self._finish(d, deliver=deliver, then=then))
+        return out
+
+    def run(self, dispatches: Iterable[_Dispatched], *, deliver: bool
+            ) -> Dict[str, Dict[str, np.ndarray]]:
+        """Serve one flush's launches: dispatch every one, in order, then
+        finish each in the same order.  The first launch's copy to the
+        host starts at its dispatch, and each next one's when the copy
+        before it has landed, so the copies cross the host link one after
+        another while the host absorbs the launch before, and the device
+        runs the later kernels under the earlier copies.
+
+        A dispatch that raises (the fault seam, tracing, the kernel call)
+        propagates only after every launch dispatched before it is
+        finished, its words parked in the outboxes (``deliver=False``):
+        those members are served and a retry launches only the rest.  A
+        fetch that raises drops the launches after it unabsorbed, their
+        demand parked at the same absolute rows for a bit-exact retry."""
+        launched: List[_Dispatched] = []
+        dispatched = False
+        try:
+            for d in dispatches:
+                if not launched:
+                    d.words.copy_to_host_async()
+                launched.append(d)
+            dispatched = True
+        finally:
+            if not dispatched:        # a dispatch raised: it propagates
+                self._finish_all(launched, deliver=False)
+        return self._finish_all(launched, deliver=deliver)
 
     def _used_lanes(self, rows: Sequence[int],
                     svcs: Sequence[PRNGService]) -> int:
@@ -476,13 +533,13 @@ class GangScheduler:
         return sum(r * svc.lanes_per_client * len(svc._active())
                    for r, svc in zip(rows, svcs))
 
-    def launch(self, key: Tuple,
-               members: List[Tuple[str, PRNGService, int, np.ndarray]],
-               *, deliver: bool = True,
-               slo: Optional[str] = None) -> Dict[str, Dict[str, np.ndarray]]:
-        """Serve one flush of ``members`` (each with its prepare_rows plan)
-        with the planner-chosen launch shape (``slo`` constrains the
-        choice set — see ``_decide``).
+    def dispatch(self, key: Tuple,
+                 members: List[Tuple[str, PRNGService, int, np.ndarray]],
+                 *, slo: Optional[str] = None) -> Iterator[_Dispatched]:
+        """Dispatch one flush of ``members`` (each with its prepare_rows
+        plan) with the planner-chosen launch shape (``slo`` constrains
+        the choice set — see ``_decide``), one launch at a time: each part
+        is dispatched when ``run`` asks for it.
 
         However the plan shapes launches, every member advances by a row
         count >= its own demand with overdraw buffered, so delivered words
@@ -495,18 +552,15 @@ class GangScheduler:
                             for _, _, n, _ in members)
             dec = self._decide(key, members, demands, slo)
             self.decisions[dec["kind"]] += 1
-        out: Dict[str, Dict[str, np.ndarray]] = {}
         for part in dec["parts"]:
             sub = [members[i] for i in part["members"]]
             if part["kind"] == "solo":
-                out.update(self._launch_solo(
-                    sub[0], demands[part["members"][0]], deliver=deliver))
+                yield self._dispatch_solo(sub[0],
+                                          demands[part["members"][0]])
             else:
-                out.update(self._launch_group(
+                yield self._dispatch_group(
                     key, sub, [demands[i] for i in part["members"]],
-                    layout=part["layout"], ragged=part["ragged"],
-                    deliver=deliver))
-        return out
+                    layout=part["layout"], ragged=part["ragged"])
 
 
 class OscillatorFarm:
@@ -806,7 +860,9 @@ class OscillatorFarm:
         Cores are grouped by gang-compatibility signature (``_compat_key``);
         each group with pending work costs one stacked-weight launch
         (``gang=False``: one launch per core, the legacy path).  Delivered
-        words are bit-identical either way.
+        words are bit-identical either way.  Every launch of the flush is
+        dispatched before any is fetched, and each is then fetched and
+        absorbed in launch order (``GangScheduler.run``).
 
         ``max_wait_rows`` is the deadline knob: a group whose total needed
         rows is below it is *deferred* — no launch, its tenants keep
@@ -850,25 +906,26 @@ class OscillatorFarm:
                 launching.append((key, cores))
             else:
                 deferred_now.update(cores)
-        out: Dict[str, Dict[str, np.ndarray]] = {}
         launching_cores = {c for _, cores in launching for c in cores}
         slo_by_core = slo_by_core or {}
-        for key, cores in launching:
-            classes = {slo_by_core.get(c) for c in cores}
-            group_slo = ("latency" if "latency" in classes
-                         else "bulk" if classes == {"bulk"} else None)
-            if self.gang and len(cores) > 1:
-                served = self._sched.launch(
-                    key, [(c, self.services[c], plans[c][0], plans[c][1])
-                          for c in cores], deliver=deliver, slo=group_slo)
-                out.update(served)
-            else:
-                for c in cores:
-                    svc = self.services[c]
-                    out.update(self._sched._launch_solo(
-                        (c, svc, plans[c][0], plans[c][1]),
-                        _round_rows(plans[c][0], svc.config.t_block),
-                        deliver=deliver))
+
+        def dispatches() -> Iterator[_Dispatched]:
+            for key, cores in launching:
+                classes = {slo_by_core.get(c) for c in cores}
+                group_slo = ("latency" if "latency" in classes
+                             else "bulk" if classes == {"bulk"} else None)
+                if self.gang and len(cores) > 1:
+                    yield from self._sched.dispatch(
+                        key, [(c, self.services[c], plans[c][0], plans[c][1])
+                              for c in cores], slo=group_slo)
+                else:
+                    for c in cores:
+                        svc = self.services[c]
+                        yield self._sched._dispatch_solo(
+                            (c, svc, plans[c][0], plans[c][1]),
+                            _round_rows(plans[c][0], svc.config.t_block))
+
+        out = self._sched.run(dispatches(), deliver=deliver)
         # Launch-free delivery pass for cores with nothing to launch (their
         # buffers/outboxes may still owe words).  Deferred cores are fully
         # skipped: their buffers do not cover their pending requests yet.

@@ -332,8 +332,9 @@ class PRNGService:
         draws their buffers do not cover.  The others ride it, frozen."""
         return [c for c in self._by_slot() if c.pending - len(c.buf) > 0]
 
-    def _launch(self, n_rows: int, offsets: jax.Array):
-        """The one batched pool launch: ((n_rows, S_pool) words, new state).
+    def _dispatch(self, n_rows: int, offsets: jax.Array):
+        """Issue the one batched pool launch, without waiting for it:
+        ((n_rows, S_pool) device words, new state).
 
         Does NOT assign ``pool_x`` — ``absorb()`` owns that, because idle
         lanes must be rolled back against the pre-launch pool.
@@ -344,6 +345,13 @@ class PRNGService:
             config=self.config, mesh=self.mesh, mesh_axis=self.mesh_axis)
         self.launches += 1
         self.tracer.launched(self.mesh, self.mesh_axis, new_x)
+        return words, new_x
+
+    def _launch(self, n_rows: int, offsets: jax.Array):
+        """The one batched pool launch: ((n_rows, S_pool) host words, new
+        state); the words' copy to the host starts at dispatch."""
+        words, new_x = self._dispatch(n_rows, offsets)
+        words.copy_to_host_async()
         return self.tracer.fetch(words), new_x
 
     # -- resumability -------------------------------------------------------

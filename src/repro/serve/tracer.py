@@ -36,11 +36,14 @@ TIMERS = ("plan", "stack", "launch", "launch_wait", "launch_copy", "absorb",
 #: wrote on the host (tenant buffers and the health monitor's sample),
 #: the launches of pools on a mesh of more than one device and those of
 #: them that ran split over every device of the mesh, the sharded launch
-#: callables built (``Tracer.launched``), and the fetches whose words the
-#: host assembled from more than one device buffer (``Tracer.fetch``).
+#: callables built (``Tracer.launched``), the fetches whose words the
+#: host assembled from more than one device buffer, every launch fetched,
+#: and the fetches that began while a later launch of the same flush was
+#: already dispatched (``Tracer.fetch``).
 COUNTERS = ("flushes", "queue_wait_s", "draws_committed", "lanes_computed",
             "lanes_used", "absorb_words_copied", "mesh_launches",
-            "mesh_launches_split", "launch_builds", "fetch_assembled")
+            "mesh_launches_split", "launch_builds", "fetch_assembled",
+            "fetches", "fetches_overlapped")
 
 _OFF = contextlib.nullcontext()
 
@@ -114,17 +117,21 @@ class Tracer:
             sums.update(mesh_launches=1, mesh_launches_split=int(split))
         self.count(**sums)
 
-    def fetch(self, words: jax.Array) -> np.ndarray:
+    def fetch(self, words: jax.Array, *,
+              overlapped: bool = False) -> np.ndarray:
         """A launch's words on the host.  Traced, as two spans: the wait
-        for the device to finish the launch, then the device-to-host
-        copy; and counted in ``fetch_assembled`` when the words lie in
-        more than one device buffer, which the copy assembles on the
-        host."""
+        for the device to finish the launch, then what remains of the
+        device-to-host copy (started early by ``copy_to_host_async``);
+        counted in ``fetches``, in ``fetches_overlapped`` when a later
+        launch of the same flush was already dispatched, and in
+        ``fetch_assembled`` when the words lie in more than one device
+        buffer, which the copy assembles on the host."""
         if self._totals is None:
             return np.asarray(words)
-        self.count(fetch_assembled=int(
-            not words.sharding.is_fully_replicated
-            and len(words.addressable_shards) > 1))
+        self.count(fetches=1, fetches_overlapped=int(overlapped),
+                   fetch_assembled=int(
+                       not words.sharding.is_fully_replicated
+                       and len(words.addressable_shards) > 1))
         with self.span("farm.launch.wait", "launch_wait"):
             jax.block_until_ready(words)
         with self.span("farm.launch.copy", "launch_copy"):

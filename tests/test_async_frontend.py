@@ -271,19 +271,19 @@ def test_partial_flush_failure_drops_no_absorbed_words():
         farm = two_group_farm(clock=fc)
         async with AsyncOscillatorFarm(farm, clock=fc) as af:
             svc_b = farm.services["b"]
-            orig = svc_b._launch
+            orig = svc_b._dispatch
 
             def boom(*a, **kw):
                 raise RuntimeError("core b launch failed")
 
-            svc_b._launch = boom
+            svc_b._dispatch = boom
             fa = af.submit("a", "t", 100, deadline_ms=0)
             fb = af.submit("b", "t", 100, deadline_ms=0)
             await af.drain()
             # whole batch failed loudly (a's group had already absorbed)
             assert isinstance(fa.exception(), RuntimeError)
             assert isinstance(fb.exception(), RuntimeError)
-            svc_b._launch = orig
+            svc_b._dispatch = orig
         out = farm.flush()            # a: parked words; b: retried pending
         solo = two_group_farm(gang=False)
         np.testing.assert_array_equal(out["a"]["t"], solo.draw("a", "t", 100))

@@ -2,7 +2,7 @@
 every device, bit-identical to the unsharded launch.
 
 A core that gangs with no other (the 4-16-4 core of a farm whose other
-cores are 3-8-3) takes the solo path, ``PRNGService._launch`` ->
+cores are 3-8-3) takes the solo path, ``PRNGService._dispatch`` ->
 ``ops.chaotic_bits(..., mesh=)``.  On a mesh of more than one device that
 launch runs one jitted ``shard_map`` per step count, built once and
 reused, as the gang launches are.  A pool that does not divide the device

@@ -30,6 +30,7 @@ to silence).  Quality-gate tests use the trained registry weights, whose
 streams pass; only the FaultPlan's poisoned sampling fails them.
 """
 import asyncio
+import random
 
 import numpy as np
 import pytest
@@ -44,6 +45,7 @@ from repro.serve.health import CoreQuarantined, HealthMonitor
 from repro.serve.journal import replay_journal
 
 from test_async_frontend import CAND, _farm, _params, _run
+from test_flush_overlap import CLIENTS, CORES, five_core_farm
 
 # Launch-fault tests: a window this large never fills from test traffic,
 # so the quality gate stays silent and only launch supervision is on
@@ -254,6 +256,88 @@ def test_transient_retries_serve_bit_identical_words():
     _run(faulty())
     _run(clean())
     for a, b in zip(results["faulty"], results["clean"]):
+        assert np.array_equal(a, b)
+
+
+# A flush of the five-core farm launches the 3-8-3 gang, then the 4-16-4
+# core alone; with seed 1 the solo core's first coin fails and its second
+# passes, so the plan fails the second launch of the first flush, once.
+GANGED = [core for core in CORES if core != "hyper"]
+
+
+def _second_launch_fails():
+    return FaultPlan(seed=1, transient_rate=0.5, transient_cores={"hyper"})
+
+
+def _spy_seam(faults):
+    """Record the cores of every launch the plan's seam sees."""
+    calls = []
+    on_launch = faults.on_launch
+
+    def spy(cores):
+        calls.append(tuple(cores))
+        on_launch(cores)
+    faults.on_launch = spy
+    return calls
+
+
+def test_a_failed_second_launch_finishes_the_first_before_raising():
+    """Every launch of a flush is dispatched before any is fetched, so the
+    gang is in flight when the solo dispatch fails: it is fetched and
+    absorbed, its words parked, before the error propagates; the retried
+    flush launches only the solo core, and every word is the clean
+    farm's."""
+    faults = _second_launch_fails()
+    farm, clean = five_core_farm(faults=faults), five_core_farm()
+    for f in (farm, clean):
+        for core in CORES:
+            for client in CLIENTS:
+                f.request(core, client, 300)
+    with pytest.raises(InjectedFault):
+        farm.flush()
+    for core in GANGED:
+        svc = farm.services[core]
+        assert svc.rows_needed() == 0
+        assert all(svc.outbox_words(c) == 300 for c in CLIENTS)
+    assert farm.services["hyper"].rows_needed() > 0
+    assert (farm.gang_launches, farm.services["hyper"].launches) == (1, 0)
+    out, ref = farm.flush(), clean.flush()
+    assert (farm.gang_launches, farm.services["hyper"].launches) == (1, 1)
+    for core in CORES:
+        for client in CLIENTS:
+            np.testing.assert_array_equal(out[core][client],
+                                          ref[core][client])
+
+
+def test_a_supervised_retry_relaunches_only_the_failed_second_launch():
+    results = {}
+
+    async def serve(name, faults=None, health=None):
+        fc = FakeClock()
+        farm = five_core_farm(clock=fc, faults=faults)
+        async with AsyncOscillatorFarm(farm, clock=fc, offload=False,
+                                       health=health) as af:
+            futs = [af.submit(core, client, 300)
+                    for core in CORES for client in CLIENTS]
+            await _drive(fc, futs)
+            results[name] = farm, [f.result() for f in futs]
+
+    faults = _second_launch_fails()
+    calls = _spy_seam(faults)
+    health = _health(breaker_threshold=10, seed=1)
+    _run(serve("faulty", faults, health))
+    _run(serve("clean"))
+    farm, words = results["faulty"]
+    # the parent's seam sequence: the gang, the failed solo launch, and
+    # the retry of the solo launch alone; two coins drawn, both solo
+    assert calls == [tuple(GANGED), ("hyper",), ("hyper",)]
+    coins = random.Random(1)
+    coins.random(), coins.random()
+    assert faults._rng.getstate() == coins.getstate()
+    assert faults.injected["transient"] == 1
+    assert health.stats["retries"] == 1
+    assert (farm.gang_launches, farm.services["hyper"].launches) == (1, 1)
+    for a, b in zip(words, results["clean"][1]):
         assert np.array_equal(a, b)
 
 

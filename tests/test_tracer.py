@@ -44,7 +44,7 @@ HARNESS = {"frontend.commit", "frontend.resolve", "farm.flush",
 NEW_METRICS = ("queue_wait_ms_mean.bulk", "commit_ms_per_mword.bulk",
                "resolve_ms_per_mword.bulk", "device_wait_ms_per_mword.bulk",
                "copy_ms_per_mword.bulk", "useful_lane_share.bulk",
-               "absorb_copies_per_word.bulk")
+               "absorb_copies_per_word.bulk", "overlapped_fetch_share.bulk")
 
 
 class TickClock:
@@ -116,7 +116,8 @@ def test_off_tracer_reads_no_clock_and_annotates_nothing(monkeypatch):
 
 def test_one_gang_flush_is_timed_read_by_read():
     """A stacked gang flush under the ticking clock: plan (the planner's
-    decision and the plan), stack, launch holding wait and copy, absorb."""
+    decision and the plan), stack, launch in two halves (the dispatch, 1
+    ms; the fetch holding wait and copy, 5 ms), absorb."""
     farm = _farm([1, 1], clock=TickClock())
     for core in farm.cores:
         farm.request(core, "t0", 4 * LANES)
@@ -124,7 +125,7 @@ def test_one_gang_flush_is_timed_read_by_read():
     st = farm.profile_stats
     assert st["plan"] == pytest.approx(2 * MS)
     assert st["stack"] == pytest.approx(1 * MS)
-    assert st["launch"] == pytest.approx(5 * MS)
+    assert st["launch"] == pytest.approx(6 * MS)
     assert st["launch_wait"] == pytest.approx(1 * MS)
     assert st["launch_copy"] == pytest.approx(1 * MS)
     assert st["launch_wait"] + st["launch_copy"] <= st["launch"]
@@ -137,7 +138,7 @@ def test_a_solo_launch_is_timed_as_a_gang_launch():
     farm.request("core0", "t0", 4 * LANES)
     farm.flush()
     st = farm.profile_stats
-    assert st["launch"] == pytest.approx(5 * MS)
+    assert st["launch"] == pytest.approx(6 * MS)
     assert st["launch_wait"] == pytest.approx(1 * MS)
     assert st["launch_copy"] == pytest.approx(1 * MS)
     assert st["absorb"] == pytest.approx(1 * MS)
@@ -238,7 +239,8 @@ def test_the_new_readers_by_hand():
     st = {"queue_wait_s": 0.3, "draws_committed": 100.0, "commit": 0.02,
           "resolve": 0.5, "launch_wait": 0.1, "launch_copy": 0.7,
           "lanes_used": 900.0, "lanes_computed": 1000.0,
-          "absorb_words_copied": 4_020_480.0}
+          "absorb_words_copied": 4_020_480.0, "fetches": 4.0,
+          "fetches_overlapped": 2.0}
     obs = {"stages": st, "words": 4_000_000}
     want = {"queue_wait_ms_mean.bulk": 3.0,
             "commit_ms_per_mword.bulk": 5.0,
@@ -246,7 +248,8 @@ def test_the_new_readers_by_hand():
             "device_wait_ms_per_mword.bulk": 25.0,
             "copy_ms_per_mword.bulk": 175.0,
             "useful_lane_share.bulk": 90.0,
-            "absorb_copies_per_word.bulk": 1.00512}
+            "absorb_copies_per_word.bulk": 1.00512,
+            "overlapped_fetch_share.bulk": 50.0}
     for name, value in want.items():
         assert _reader(name)(obs) == pytest.approx(value), name
 
@@ -374,6 +377,21 @@ def test_a_fetch_is_counted_where_the_host_assembles_its_words(
     got = on_a_mesh[words]
     assert got["fetch_assembled"] == assembled
     assert got["fetched_equal"]
+
+
+@pytest.mark.parametrize("overlapped", [False, True])
+def test_a_fetch_is_counted_and_overlapped_under_a_later_dispatch(
+        overlapped):
+    tr = Tracer(TickClock())
+    words = jax.numpy.arange(6, dtype=jax.numpy.uint32)
+    words.copy_to_host_async()
+    assert np.array_equal(tr.fetch(words, overlapped=overlapped),
+                          np.arange(6))
+    st = tr.stats()
+    assert (st["fetches"], st["fetches_overlapped"]) == (1.0,
+                                                         float(overlapped))
+    assert st["launch_wait"] == pytest.approx(MS)
+    assert st["launch_copy"] == pytest.approx(MS)
 
 
 def test_an_untraced_fetch_counts_nothing():
